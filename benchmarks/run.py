@@ -10,7 +10,6 @@ Run: PYTHONPATH=src python -m benchmarks.run [--only fig6c]
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
 import shutil
@@ -238,7 +237,7 @@ def train_step_micro() -> None:
     import jax
     import jax.numpy as jnp
 
-    from repro import compat, configs
+    from repro import configs
     from repro.config import RunConfig, TrainConfig
     from repro.core.engine import ZeroInfinityEngine
     from repro.launch.mesh import make_local_mesh
@@ -249,7 +248,7 @@ def train_step_micro() -> None:
     state = eng.init_state(jax.random.PRNGKey(0))
     batch = {"tokens": jnp.ones((4, 128), jnp.int32),
              "labels": jnp.ones((4, 128), jnp.int32)}
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = jax.jit(eng.make_train_step())
         state, m = step(state, batch)  # compile
         jax.block_until_ready(m["loss"])
@@ -508,21 +507,24 @@ def serving_micro() -> None:
 
 
 # ---------------------------------------------------------------------------
-# Kernel microbenches (interpret mode — correctness-path timing)
+# Kernel microbenches (Pallas interpret mode on the CPU: correctness-path
+# timing, no device number)
 # ---------------------------------------------------------------------------
 
 def kernels_micro() -> None:
+    import jax
     import jax.numpy as jnp
 
     from repro.kernels import ops
 
+    mode = "interpret" if jax.default_backend() == "cpu" else "compiled"
     p = jnp.ones((1 << 16,), jnp.float32)
     kw = dict(lr=jnp.float32(1e-3), beta1=0.9, beta2=0.95, eps=1e-8,
               weight_decay=0.1, bc1=jnp.float32(0.1), bc2=jnp.float32(0.05))
     ops.fused_adam(p, p, p, p, **kw)
     t0 = time.perf_counter()
     ops.fused_adam(p, p, p, p, **kw)[0].block_until_ready()
-    emit("kernels/fused_adam_64k", (time.perf_counter() - t0) * 1e6, "interpret")
+    emit("kernels/fused_adam_64k", (time.perf_counter() - t0) * 1e6, mode)
 
     x = jnp.ones((256, 512), jnp.float32)
     w = jnp.ones((512, 256), jnp.float32)
@@ -530,7 +532,7 @@ def kernels_micro() -> None:
     t0 = time.perf_counter()
     ops.tiled_matmul(x, w).block_until_ready()
     emit("kernels/tiled_matmul_256x512x256", (time.perf_counter() - t0) * 1e6,
-         "interpret")
+         mode)
 
     q = jnp.ones((1, 4, 128, 64), jnp.float32)
     k = jnp.ones((1, 4, 128, 64), jnp.float32)
@@ -538,43 +540,7 @@ def kernels_micro() -> None:
     t0 = time.perf_counter()
     ops.flash_attention(q, k, k).block_until_ready()
     emit("kernels/flash_attention_128", (time.perf_counter() - t0) * 1e6,
-         "interpret")
-
-
-# ---------------------------------------------------------------------------
-# Roofline table (from the dry-run artifacts — EXPERIMENTS.md §Roofline source)
-# ---------------------------------------------------------------------------
-
-PERF_TAGS = ("_puredp", "_rematdots", "_sbf16", "_rd_sbf16", "_tile8",
-             "_mcbf16", "_combo", "_podscope", "_base2", "_rematnone",
-             "_puredp_rn", "_sd_rd", "_moez2", "_routerbf16", "_rb_mcbf16",
-             "_gathercomb", "_gc_all", "_xz3", "_xz3_nopf", "_pd_rd", "_pd2",
-             "_pd_sbf16", "_pd_moez2")
-
-
-def _is_perf_variant(base: str) -> bool:
-    # baseline cells are exactly "<mesh>__<arch>__<shape>"
-    parts = base.split("__")
-    return len(parts) != 3 or parts[2] not in (
-        "train_4k", "prefill_32k", "decode_32k", "long_500k")
-
-
-def roofline_table() -> None:
-    d = os.path.join(os.path.dirname(__file__), "..", "experiments", "dryrun")
-    files = sorted(glob.glob(os.path.join(d, "*.json")))
-    n = 0
-    for f in files:
-        rec = json.load(open(f))
-        base = os.path.basename(f)[:-5]
-        if _is_perf_variant(base):
-            continue  # perf-iteration variants reported in EXPERIMENTS.md §Perf
-        if rec.get("status") != "ok" or "roofline" not in rec:
-            continue
-        r = rec["roofline"]
-        emit(f"roofline/{rec['mesh']}/{rec['arch']}/{rec['shape']}", 0.0,
-             f"bottleneck={r['bottleneck']};frac={r['roofline_fraction']:.4f}")
-        n += 1
-    emit("roofline/cells_reported", 0.0, n)
+         mode)
 
 
 BENCHES = {
@@ -593,7 +559,6 @@ BENCHES = {
     "serving": serving_micro,
     "executor": executor_micro,
     "kernels": kernels_micro,
-    "roofline": roofline_table,
 }
 
 
@@ -673,10 +638,12 @@ def main() -> None:
                          "`executor` bench additionally emits measured "
                          "efficiency / stall-fraction rows")
     from repro import plan as plan_mod
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.runtime import trace
 
     plan_mod.add_plan_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.trace:
         trace.enable()
     keys = args.only.split(",") if args.only else list(BENCHES)

@@ -2,6 +2,3 @@
 in JAX, with a GSPMD-native engine and a paper-faithful explicit-collective
 engine behind one executor interface (see ``repro.core.executor``).
 """
-from repro import compat
-
-compat.install()

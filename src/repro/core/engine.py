@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.config import RunConfig, ShapeConfig
 from repro.core import partition as pt
 from repro.models import registry
@@ -47,7 +46,7 @@ def _device_put_tree(tree, shardings):
 
 
 class ZeroInfinityEngine:
-    def __init__(self, run: RunConfig, mesh: Mesh, *, host_offload_in_graph: Optional[bool] = None):
+    def __init__(self, run: RunConfig, mesh: Mesh):
         self.run = run
         self.mesh = mesh
         mc, pc = run.model, run.parallel
@@ -57,17 +56,16 @@ class ZeroInfinityEngine:
         self.opt_rules = pt.make_rules(mc, mesh, pc, for_state="opt")
         self.bundle = registry.build(mc, self.act_rules, pc)
         self.opt_defs = adam.state_defs(self.bundle.defs)
-        if host_offload_in_graph is None:
-            host_offload_in_graph = host_memory_kind_supported()
-        self.host_ok = host_offload_in_graph
+        host = "host" in (run.offload.param_tier, run.offload.opt_tier)
+        self.host_kind = pt.host_memory_kind(mesh) if host else None
 
     # ------------------------------------------------------------------
     # shardings & specs
     # ------------------------------------------------------------------
 
     def _tier_kind(self, tier: str) -> Optional[str]:
-        if tier == "host" and self.host_ok:
-            return compat.host_memory_kind()
+        if tier == "host":
+            return self.host_kind
         return None  # device, nvme (nvme states never enter the graph)
 
     def param_shardings(self):
@@ -143,7 +141,7 @@ class ZeroInfinityEngine:
             params = pt.init_tree(rng, self.bundle.defs)
             return params
 
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             params = jax.jit(_init, out_shardings=shardings)(rng)
             if self.run.opt_offgraph:
                 # master/m/v never enter device memory: they live in the
@@ -163,9 +161,9 @@ class ZeroInfinityEngine:
         pc = run.parallel
         bundle = self.bundle
         grad_shardings = self.grad_shardings()
-        opt_host = (run.offload.opt_tier == "host" and self.host_ok
+        opt_host = (run.offload.opt_tier == "host" and self.host_kind
                     and not grads_only)
-        param_host = run.offload.param_tier == "host" and self.host_ok
+        param_host = run.offload.param_tier == "host" and self.host_kind
         param_shardings = self.param_shardings() if param_host else None
 
         # families with routing/step statistics (moe) expose loss_stats: the
@@ -221,6 +219,10 @@ class ZeroInfinityEngine:
                 gnorm = _global_norm(grads)
                 return grads, {"loss": loss, "grad_norm": gnorm, **aux}
             new_params, new_opt = adam.apply_updates(grads, opt, tc, params_prev=params)
+            # metrics read the device-side states: a value computed from a
+            # leaf already moved to host would leave the step in host memory
+            metrics = {"loss": loss, "grad_norm": _global_norm(grads),
+                       "lr": adam.lr_at(tc, new_opt.step), **aux}
             if param_host:  # updated bf16 params return to pinned host memory
                 new_params = jax.tree.map(
                     lambda x, s: jax.device_put(x, s), new_params, param_shardings)
@@ -228,35 +230,40 @@ class ZeroInfinityEngine:
                 new_opt = jax.tree.map(
                     lambda x, s: jax.device_put(x, s), new_opt,
                     self._opt_state_from(self.opt_shardings()))
-            metrics = {"loss": loss, "grad_norm": _global_norm(grads),
-                       "lr": adam.lr_at(tc, new_opt.step), **aux}
             return {"params": new_params, "opt": new_opt}, metrics
 
         return train_step
+
+    def jit_train_step(self, *, grads_only: bool = False, donate: bool = False):
+        """``make_train_step`` under jit, its state outputs placed like
+        ``state_shardings``, memory kind included: the state a step returns
+        is laid out exactly as the next step takes it, host tiers in
+        ``pinned_host``."""
+        step = self.make_train_step(grads_only=grads_only)
+        out = (None, None) if grads_only else (self.state_shardings(), None)
+        kw = {"donate_argnums": (0,)} if donate and not grads_only else {}
+        return jax.jit(step, out_shardings=out, **kw)
 
     def lower_train(self, shape: ShapeConfig, *, grads_only: Optional[bool] = None,
                     donate: bool = True):
         if grads_only is None:  # resolve from the configured tiers
             grads_only = self.run.opt_offgraph
-        step = self.make_train_step(grads_only=grads_only)
-        state_specs = self.state_specs()
-        batch = self.batch_specs(shape)
-        kw = {"donate_argnums": (0,)} if donate and not grads_only else {}
-        with compat.set_mesh(self.mesh):
-            return jax.jit(step, **kw).lower(state_specs, batch)
+        step = self.jit_train_step(grads_only=grads_only, donate=donate)
+        with jax.set_mesh(self.mesh):
+            return step.lower(self.state_specs(), self.batch_specs(shape))
 
     # ------------------------------------------------------------------
     # serve steps
     # ------------------------------------------------------------------
 
     def lower_prefill(self, shape: ShapeConfig):
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             return jax.jit(self.bundle.prefill).lower(self.param_specs(), self.batch_specs(shape))
 
     def lower_decode(self, shape: ShapeConfig):
         batch = self.batch_specs(shape)
         cache = self.cache_specs(shape)
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             return jax.jit(self.bundle.decode_step).lower(self.param_specs(), cache, batch)
 
     def lower(self, shape: ShapeConfig):
@@ -270,8 +277,3 @@ class ZeroInfinityEngine:
 def _global_norm(tree) -> jax.Array:
     leaves = [jnp.sum(jnp.square(x.astype(jnp.float32))) for x in jax.tree.leaves(tree)]
     return jnp.sqrt(sum(leaves))
-
-
-def host_memory_kind_supported() -> bool:
-    """Probe whether the backend supports host-tier shardings in jit."""
-    return compat.host_offload_supported()

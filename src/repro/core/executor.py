@@ -11,7 +11,7 @@ that unification point for the repo's two engines:
     collectives in shard_map.
 
 Both satisfy ``EngineProtocol`` (init_state / make_train_step /
-state_shardings / lower_train); ``make_engine`` selects one from
+jit_train_step / state_shardings / lower_train); ``make_engine`` selects one from
 ``RunConfig.parallel.engine``. ``InfinityExecutor`` then drives the
 configured placement, independently per state class
 (``offload.param_tier`` / ``grad_tier`` / ``opt_tier``):
@@ -54,7 +54,6 @@ import jax.numpy as jnp
 import ml_dtypes
 import numpy as np
 
-from repro import compat
 from repro.config import RunConfig, ShapeConfig
 from repro.core import qformat
 from repro.core import schedule as sched_mod
@@ -73,6 +72,8 @@ class EngineProtocol(Protocol):
     def init_state(self, rng: jax.Array): ...
 
     def make_train_step(self, *, grads_only: bool = False): ...
+
+    def jit_train_step(self, *, grads_only: bool = False): ...
 
     def state_shardings(self): ...
 
@@ -487,8 +488,7 @@ class InfinityExecutor:
             self._step_fn = (self._layered_moe_step() if self.is_moe
                              else self._layered_step())
             return self._step_fn
-        with compat.set_mesh(self.mesh):
-            jit_step = jax.jit(self.engine.make_train_step(grads_only=self.offgraph))
+        jit_step = self.engine.jit_train_step(grads_only=self.offgraph)
 
         if not self.offgraph and not self.param_nvme:
             step = jit_step  # fully in-graph (device/host tiers)
@@ -1093,11 +1093,9 @@ class InfinityExecutor:
         to a pinned-host target sharding (per-device assembly cannot target
         a non-default memory kind)."""
         sh = like.sharding
-        kind = getattr(sh, "memory_kind", None)
-        dev_kind = compat.default_memory_kind()
         asm_sh = sh
-        if kind is not None and dev_kind is not None and kind != dev_kind:
-            asm_sh = sh.with_memory_kind(dev_kind)
+        if sh.memory_kind not in (None, "device"):
+            asm_sh = sh.with_memory_kind("device")
         pieces = []
         for d in np.asarray(self.mesh.devices).flat:
             piece = np.asarray(by_rank[self._rank_of[d]]).astype(

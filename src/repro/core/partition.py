@@ -236,6 +236,25 @@ def spec_tree(defs, rules: AxisRules):
     )
 
 
+def host_memory_kind(mesh: Mesh) -> Optional[str]:
+    """The memory kind that places a state in host DRAM on ``mesh``.
+
+    ``pinned_host`` on TPU and GPU. None on the CPU, whose device memory
+    already is host DRAM and whose jit cannot lower host placements: there
+    the host tier is device placement. Read from the mesh's own devices, so
+    a mesh of described (compile-only) TPU devices gets the TPU's answer.
+    """
+    dev = mesh.devices.flat[0]
+    if dev.platform == "cpu":
+        return None
+    kinds = {m.kind for m in dev.addressable_memories()}
+    if "pinned_host" not in kinds:
+        raise ValueError(
+            f"{dev.device_kind} exposes memory kinds {sorted(kinds)}, no "
+            "pinned_host: the host tier cannot be placed on this backend")
+    return "pinned_host"
+
+
 def sharding_tree(defs, rules: AxisRules, mesh: Mesh, memory_kind: Optional[str] = None):
     def mk(d: ParamDef):
         spec = rules.spec(d.axes, d.shape)

@@ -47,7 +47,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.config import RunConfig, ShapeConfig
 from repro.core import partition as pt
 from repro.models import common as cm
@@ -89,8 +88,8 @@ class ExplicitZero3Engine:
         shards; the partitioned Adam update runs in-graph.
       * ``opt_tier=host``   — same layout, placed with the backend's host
         memory kind (``pinned_host``); the step streams them HBM<->host
-        around the compute. On backends without a distinct host tier (CPU)
-        this degrades to device placement, so the code path stays identical.
+        around the compute. On the CPU, whose jit cannot place host memory
+        kinds, this is device placement (``partition.host_memory_kind``).
       * ``param_tier=host`` — the bf16 (L, P/dp) compute shards live in
         pinned host memory and are streamed to HBM ahead of the prefetched
         per-layer all-gathers (same degrade rule on CPU).
@@ -131,8 +130,8 @@ class ExplicitZero3Engine:
             self.defs = transformer.param_defs(run.model)
         self.opt_tier = run.offload.opt_tier
         self.offgraph = run.opt_offgraph
-        hk = (compat.host_memory_kind()
-              if compat.host_offload_supported() else None)
+        hk = (pt.host_memory_kind(mesh)
+              if "host" in (self.opt_tier, run.offload.param_tier) else None)
         self.opt_host_kind = (hk if self.opt_tier == "host" and not self.offgraph
                               else None)
         self.param_host_kind = hk if run.offload.param_tier == "host" else None
@@ -529,7 +528,7 @@ class ExplicitZero3Engine:
         out_specs = ((state_specs, flat_spec, metric_spec) if grads_only
                      else (state_specs, metric_spec))
 
-        step_fn = compat.shard_map(
+        step_fn = jax.shard_map(
             sharded_step, mesh=self.mesh,
             in_specs=(state_specs, batch_spec),
             out_specs=out_specs,
@@ -548,7 +547,6 @@ class ExplicitZero3Engine:
             return step_fn
 
         host_shardings = self.state_shardings()
-        dev_kind = compat.default_memory_kind()
 
         def to_kind(state, kind):
             out = dict(state)
@@ -558,7 +556,7 @@ class ExplicitZero3Engine:
             return out
 
         def host_tier_step(state, batch):
-            res = step_fn(to_kind(state, dev_kind), batch)
+            res = step_fn(to_kind(state, "device"), batch)
             if grads_only:
                 new_state, g32, metrics = res
                 return to_kind(new_state, None), g32, metrics
@@ -629,9 +627,9 @@ class ExplicitZero3Engine:
         other_specs, _ = self._rep_specs()
 
         def smap(f, in_specs, out_specs):
-            fn = compat.shard_map(f, mesh=mesh, in_specs=in_specs,
+            fn = jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                                   out_specs=out_specs, check_vma=False)
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 return jax.jit(fn)
 
         def _gather_blk(row):
@@ -693,7 +691,7 @@ class ExplicitZero3Engine:
             return new_other, new_other_opt, new_step, \
                 {"grad_norm": gnorm, "lr": lr}
 
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             finish = jax.jit(_finish)
         fns = {
             "embed_fwd": smap(_embed_fwd, (other_specs, bspec), xspec),
@@ -824,6 +822,21 @@ class ExplicitZero3Engine:
                           for k in ("master", "m", "v")})
         return state
 
+    def jit_train_step(self, *, grads_only: bool = None):
+        """``make_train_step`` under jit, its state outputs placed like
+        ``state_shardings``, memory kind included: the state a step returns
+        is laid out exactly as the next step takes it."""
+        if grads_only is None:
+            grads_only = self.offgraph
+        sh = self.state_shardings()
+        if grads_only:  # master/m/v stay with the executor's stores
+            sh = {k: v for k, v in sh.items() if k not in ("master", "m", "v")}
+            out = (sh, None, None)
+        else:
+            out = (sh, None)
+        return jax.jit(self.make_train_step(grads_only=grads_only),
+                       out_shardings=out)
+
     def lower_train(self, shape: ShapeConfig, *, grads_only: bool = None):
         if self.is_moe:
             raise NotImplementedError(
@@ -837,5 +850,5 @@ class ExplicitZero3Engine:
             "tokens": jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=sh(P(self.axis, None))),
             "labels": jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=sh(P(self.axis, None))),
         }
-        with compat.set_mesh(self.mesh):
-            return jax.jit(self.make_train_step(grads_only=grads_only)).lower(state, batch)
+        with jax.set_mesh(self.mesh):
+            return self.jit_train_step(grads_only=grads_only).lower(state, batch)

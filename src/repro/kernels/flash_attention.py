@@ -62,8 +62,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk", "interpret"))
-def flash_attention(q, k, v, *, causal: bool = True, bq: int = 256, bk: int = 256,
-                    interpret: bool = True):
+def flash_attention(q, k, v, *, interpret: bool, causal: bool = True,
+                    bq: int = 256, bk: int = 256):
     """q: (B, H, Sq, D), k/v: (B, KV, Sk, D) with H % KV == 0 -> (B, H, Sq, D)."""
     B, H, Sq, D = q.shape
     KV, Sk = k.shape[1], k.shape[2]

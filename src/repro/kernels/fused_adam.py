@@ -44,8 +44,8 @@ def _adam_kernel(scalars_ref, p_ref, g_ref, m_ref, v_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def fused_adam_flat(p32, g32, m, v, scalars, *, block_rows: int = DEFAULT_BLOCK_ROWS,
-                    interpret: bool = True):
+def fused_adam_flat(p32, g32, m, v, scalars, *, interpret: bool,
+                    block_rows: int = DEFAULT_BLOCK_ROWS):
     """All arrays (R, 128) f32; scalars (7,) f32 = [lr,b1,b2,eps,wd,c1,c2].
 
     Returns (p32, m, v, p_bf16).

@@ -1,12 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only; on a TPU
-runtime set ``REPRO_PALLAS_COMPILE=1`` (or pass interpret=False) to run the
-compiled kernels.
+The kernels run compiled on an accelerator. They run in Pallas interpret
+mode only when the default backend is the CPU, which has no Mosaic
+lowering: that is how the CPU tests check them against ``kernels/ref.py``.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -15,8 +13,11 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import fused_adam as _ad
 from repro.kernels import tiled_matmul as _mm
 
-_INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
 LANE = _ad.LANE
+
+
+def _interpret() -> bool:
+    return jax.default_backend() == "cpu"
 
 
 def fused_adam(p32, g32, m, v, *, lr, beta1, beta2, eps, weight_decay, bc1, bc2,
@@ -39,7 +40,7 @@ def fused_adam(p32, g32, m, v, *, lr, beta1, beta2, eps, weight_decay, bc1, bc2,
                          bc1, bc2]).astype(jnp.float32)
     p2, m2, v2, _ = _ad.fused_adam_flat(flat(p32), flat(g32), flat(m), flat(v),
                                         scalars, block_rows=block_rows,
-                                        interpret=_INTERPRET)
+                                        interpret=_interpret())
 
     def unflat(x):
         return x.reshape(-1)[:n].reshape(shape)
@@ -48,18 +49,16 @@ def fused_adam(p32, g32, m, v, *, lr, beta1, beta2, eps, weight_decay, bc1, bc2,
 
 
 def tiled_matmul(x, w, **kw):
-    kw.setdefault("interpret", _INTERPRET)
-    return _mm.tiled_matmul(x, w, **kw)
+    return _mm.tiled_matmul(x, w, interpret=_interpret(), **kw)
 
 
 def quantized_matmul(x, q, scales, **kw):
     """Fused dequant-matmul on q8 wire operands (int8 quants + per-block
     fp16 scales, see ``core/qformat.py``): the full-precision weight never
     materializes in HBM — tiles dequantize in VMEM ahead of the MXU dot."""
-    kw.setdefault("interpret", _INTERPRET)
-    return _mm.quantized_matmul(x, q, scales, **kw)
+    return _mm.quantized_matmul(x, q, scales, interpret=_interpret(), **kw)
 
 
 def flash_attention(q, k, v, *, causal=True, **kw):
-    kw.setdefault("interpret", _INTERPRET)
-    return _fa.flash_attention(q, k, v, causal=causal, **kw)
+    return _fa.flash_attention(q, k, v, causal=causal, interpret=_interpret(),
+                               **kw)

@@ -35,7 +35,7 @@ def _mm_kernel(x_ref, w_ref, o_ref, acc_ref):
 
 def _qmm_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, qblock):
     """Fused dequant-matmul: the weight tile arrives as int8 quants +
-    per-block fp16 scales (the q8 wire layout, blocks along N) and is
+    per-block scales (the q8 wire layout, blocks along N) and is
     dequantized in VMEM right before the MXU dot — the full-precision W
     never exists in HBM."""
     k = pl.program_id(2)
@@ -45,9 +45,18 @@ def _qmm_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, qblock):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     bk, bn = q_ref.shape
-    s = s_ref[...].astype(jnp.float32)  # (bk, bn // qblock)
-    w = (q_ref[...].astype(jnp.float32).reshape(bk, bn // qblock, qblock)
-         * s[:, :, None]).reshape(bk, bn)
+    nb = bn // qblock
+    # scales arrive transposed, (nb, bk); a 0/1 expansion matmul spreads
+    # block b's scale over its qblock columns as a (bk, bn) tile. Mosaic
+    # cannot split the lane dim (bn -> nb x qblock) with a reshape, and
+    # HIGHEST precision keeps the expanded scales exact.
+    expand = (jax.lax.broadcasted_iota(jnp.int32, (nb, bn), 0)
+              == jax.lax.broadcasted_iota(jnp.int32, (nb, bn), 1) // qblock)
+    scale = jax.lax.dot_general(
+        s_ref[...], expand.astype(jnp.float32), (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    w = q_ref[...].astype(jnp.float32) * scale
     acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.float32), w,
                             preferred_element_type=jnp.float32)
 
@@ -57,8 +66,8 @@ def _qmm_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, qblock):
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def tiled_matmul(x, w, *, bm: int = 256, bn: int = 256, bk: int = 512,
-                 interpret: bool = True):
+def tiled_matmul(x, w, *, interpret: bool, bm: int = 256, bn: int = 256,
+                 bk: int = 512):
     """x: (M, K) @ w: (K, N) -> (M, N). VMEM per step ~ bm*bk + bk*bn + bm*bn."""
     M, K = x.shape
     K2, N = w.shape
@@ -89,8 +98,8 @@ def tiled_matmul(x, w, *, bm: int = 256, bn: int = 256, bk: int = 512,
 
 @functools.partial(jax.jit,
                    static_argnames=("bm", "bn", "bk", "interpret"))
-def quantized_matmul(x, q, scales, *, bm: int = 256, bn: int = 256,
-                     bk: int = 512, interpret: bool = True):
+def quantized_matmul(x, q, scales, *, interpret: bool, bm: int = 256,
+                     bn: int = 256, bk: int = 512):
     """x: (M, K) @ dequant(q: (K, N) int8, scales: (K, N//qblock)) -> (M, N).
 
     ``q``/``scales`` are the q8 wire layout of ``core/qformat.py``
@@ -116,13 +125,17 @@ def quantized_matmul(x, q, scales, *, bm: int = 256, bn: int = 256,
         scales = jnp.pad(scales, ((0, pk), (0, pn // qblock)))
     Mp, Kp, Np = M + pm, K + pk, N + pn
     grid = (Mp // bm, Np // bn, Kp // bk)
+    # (Np/qblock, Kp) f32: a (bn/qblock, bk) scale block meets the TPU's
+    # (8, 128) block tiling where a (bk, bn/qblock) one cannot, and v5e
+    # has no fp16 vector loads
+    scales = scales.T.astype(jnp.float32)
     out = pl.pallas_call(
         functools.partial(_qmm_kernel, qblock=qblock),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bk, bn // qblock), lambda i, j, k: (k, j)),
+            pl.BlockSpec((bn // qblock, bk), lambda i, j, k: (j, k)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),

@@ -35,12 +35,13 @@ import traceback
 
 import jax
 
-from repro import compat, configs
+from repro import configs
 from repro import plan as plan_mod
 from repro.config import (RunConfig, ParallelConfig, OffloadConfig, SHAPES,
                           ShapeConfig)
 from repro.core import model_math
 from repro.core.engine import ZeroInfinityEngine
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models import registry
 from repro.roofline import analysis
@@ -133,13 +134,14 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, *,
                                 mesh_name=mesh_name, n_chips=n_chips,
                                 model_flops_total=mf)
         print(compiled.memory_analysis())   # proves it fits
-        print(compat.cost_analysis(compiled))  # FLOPs/bytes for §Roofline
+        cost = compiled.cost_analysis() or {}
+        print(cost)  # FLOPs/bytes for §Roofline
         rec.update(status="ok", lower_s=t_lower, compile_s=t_compile,
                    n_params=eng.bundle.n_params(),
                    n_params_active=eng.bundle.n_params_active(),
                    memory_analysis=str(compiled.memory_analysis()),
                    cost_analysis={k: float(v) for k, v in
-                                  compat.cost_analysis(compiled).items()
+                                  cost.items()
                                   if isinstance(v, (int, float))},
                    roofline=roof.to_dict())
     except Exception as e:  # record the failure — these are bugs to fix
@@ -428,6 +430,7 @@ def main() -> None:
                          "busy/idle, measured vs predicted efficiency)")
     plan_mod.add_plan_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.trace or args.trace_report:
         trace.enable()

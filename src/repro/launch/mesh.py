@@ -10,17 +10,15 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
-
 
 def _auto(n: int):
-    return (compat.AxisType.Auto,) * n
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes, axis_types=_auto(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
@@ -28,7 +26,7 @@ def make_local_mesh(data: int = 1, model: int = 1):
     n = data * model
     devs = jax.devices()[:n]
     assert len(devs) == n, f"need {n} devices, have {len(jax.devices())}"
-    return compat.make_mesh((data, model), ("data", "model"), devices=devs,
+    return jax.make_mesh((data, model), ("data", "model"), devices=devs,
                             axis_types=_auto(2))
 
 

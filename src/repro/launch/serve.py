@@ -31,12 +31,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat, configs
+from repro import configs
 from repro import plan as plan_mod
 from repro.config import ParallelConfig, RunConfig, ShapeConfig
 from repro.core import kvcache, qformat
 from repro.core.engine import ZeroInfinityEngine
 from repro.core.offload import HostArrayStore, NvmeStore, PinnedBufferPool
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.runtime import metrics as metrics_mod
 from repro.runtime import trace
@@ -165,7 +166,7 @@ def run_serve(args, argv=None) -> dict:
     waiting: collections.deque = collections.deque()
 
     pc = time.perf_counter
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         # untimed ahead-of-time compile: throughput below is compute-only
         t0 = pc()
         prefill_c = jax.jit(eng.bundle.prefill).lower(
@@ -344,6 +345,7 @@ def run_serve(args, argv=None) -> dict:
 
 def main(argv=None) -> None:
     args = _parse(argv)
+    enable_compile_cache()
     if args.trace:
         trace.enable()
     out = run_serve(args, argv)

@@ -19,14 +19,16 @@ import time
 
 import jax
 
-from repro import compat, configs
+from repro import configs
 from repro import plan as plan_mod
 from repro.checkpoint.manager import CheckpointManager
 from repro.config import (RunConfig, ShapeConfig, TrainConfig, make_offload,
                           make_parallel)
 from repro.core.executor import InfinityExecutor
 from repro.data.pipeline import PrefetchLoader, SyntheticStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, maybe_init_distributed
+from repro.peaks import peaks_for
 from repro.runtime import trace
 from repro.runtime.elastic import wire_straggler
 from repro.runtime.fault import FailureInjector, StragglerMonitor, retry_loop
@@ -148,15 +150,15 @@ def make_run(args):
 
 
 def make_metrics_logger(model_flops_per_token, mesh, plan) -> MetricsLogger:
-    """MFU denominator comes from the plan's measured/declared hardware when
-    one exists; the paper-V100 default only covers manual mode."""
-    kw = {}
+    """MFU denominator: the plan's hardware when one exists, else the
+    mesh's device kind in the peaks table (none on the CPU)."""
     if plan is not None:
-        kw["peak_flops"] = float(plan.hardware.peak_flops)
-        kw["n_chips"] = int(plan.hardware.n_devices)
+        peak, n = float(plan.hardware.peak_flops), int(plan.hardware.n_devices)
     else:
-        kw["n_chips"] = len(mesh.devices.flat)
-    return MetricsLogger(model_flops_per_token=model_flops_per_token, **kw)
+        chip = peaks_for(mesh.devices.flat[0])
+        peak, n = (chip.flops if chip else None), len(mesh.devices.flat)
+    return MetricsLogger(model_flops_per_token=model_flops_per_token,
+                         peak_flops=peak, n_chips=n)
 
 
 def train_elastic(args) -> dict:
@@ -208,7 +210,9 @@ def train(args) -> dict:
     straggler = wire_straggler(
         StragglerMonitor(factor=getattr(args, "straggler_factor", 3.0)))
     retry_stats = {"restarts": 0, "recovery_s": 0.0}
-    history = {"losses": [], "restarts": 0}
+    # step_s: wall seconds per step, device work included (the loss is
+    # pulled to the host inside the timed region); step 0 compiles
+    history = {"losses": [], "step_s": [], "restarts": 0}
 
     def run_once():
         resuming = args.resume == "auto" and ckpt.latest_step() is not None
@@ -242,8 +246,9 @@ def train(args) -> dict:
                                 executor.batch_shardings(shape))
         logger = make_metrics_logger(executor.n_params_active(), mesh, plan)
         tokens = shape.global_batch * shape.seq_len
+        metrics = None
 
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             for step, batch in loader:
                 straggler.start()
                 injector.maybe_fail(step)
@@ -251,6 +256,7 @@ def train(args) -> dict:
                 loss = float(metrics["loss"])
                 dt = straggler.stop(step)
                 history["losses"].append(loss)
+                history["step_s"].append(dt)
                 if step % args.log_every == 0:
                     extras = elastic_step_metrics(
                         restarts=retry_stats["restarts"],
@@ -265,6 +271,7 @@ def train(args) -> dict:
                               {"next_step": step + 1})
         ckpt.wait()
         history["final_state"] = state
+        history["last_metrics"] = metrics
         stats = executor.bandwidth_stats()
         if stats:
             history["nvme_stats"] = stats
@@ -274,6 +281,7 @@ def train(args) -> dict:
         recovery_budget_s=args.recovery_budget, stats=retry_stats,
         on_restart=lambda n, e: print(f"restart #{n} after: {e}"))
     history["recovery_s"] = retry_stats["recovery_s"]
+    executor.close()
     if straggler.flagged:
         print(f"straggler steps flagged: {straggler.flagged}")
     return history
@@ -281,6 +289,7 @@ def train(args) -> dict:
 
 def main() -> None:
     args = build_argparser().parse_args()
+    enable_compile_cache()
     if getattr(args, "trace", None):
         trace.enable()
     t0 = time.time()

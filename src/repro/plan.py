@@ -82,8 +82,9 @@ class HardwareSpec:
 
     Capacities are absolute bytes; bandwidths are bytes/s *per device* (the
     paper's per-GPU share of each link at node scale). ``detect()`` fills
-    capacities from the live backend and leaves bandwidths at the paper's
-    nominal rates; every field takes an explicit override.
+    capacities from the live backend and an accelerator's peak FLOP/s and
+    HBM bandwidth from ``repro.peaks``; host, NVMe and interconnect rates
+    stay at the paper's nominal values. Every field takes an override.
     """
 
     n_devices: int = 1
@@ -175,14 +176,19 @@ class HardwareSpec:
                **overrides) -> "HardwareSpec":
         """Probe the live backend; any field is overridable by keyword.
 
-        Capacities come from the backend / OS (``memory_stats`` for HBM,
-        sysconf for host DRAM, ``disk_usage`` of ``nvme_dir``'s filesystem
-        for NVMe). On a CPU backend the "device" memory *is* host DRAM, so
-        ``device_mem`` falls back to the host share — which correctly yields
-        an all-device plan for CPU smoke runs. Bandwidths stay at the
-        paper's nominal per-device rates unless overridden.
+        On an accelerator, peak FLOP/s and HBM bandwidth come from the
+        peaks table (``repro.peaks``; a kind missing there raises) and HBM
+        capacity from ``memory_stats``, or the table where the backend
+        reports none. Host DRAM comes from sysconf and NVMe from
+        ``disk_usage`` of ``nvme_dir``'s filesystem. On a CPU backend the
+        "device" memory *is* host DRAM, so ``device_mem`` is the host share
+        — which correctly yields an all-device plan for CPU smoke runs —
+        and the rates stay at the paper's nominal values. Host and NVMe
+        bandwidths are never probed.
         """
         import jax
+
+        from repro.peaks import peaks_for
 
         devs = jax.devices()
         n = len(devs)
@@ -191,15 +197,14 @@ class HardwareSpec:
                              * os.sysconf("SC_PHYS_PAGES"))
         except (ValueError, OSError, AttributeError):
             host_mem = 64e9
-        device_mem = None
-        try:
-            stats = devs[0].memory_stats() or {}
-            device_mem = stats.get("bytes_limit") or stats.get(
-                "bytes_reservable_limit")
-        except Exception:
-            device_mem = None
-        if not device_mem:
+        chip = peaks_for(devs[0])
+        rates = {}
+        if chip is None:
             device_mem = host_mem / n  # CPU backend: HBM == host DRAM share
+        else:
+            stats = devs[0].memory_stats() or {}
+            device_mem = stats.get("bytes_limit") or chip.hbm_bytes
+            rates = dict(peak_flops=chip.flops, device_bw=chip.hbm_bw)
         probe = nvme_dir
         while probe and not os.path.isdir(probe):
             parent = os.path.dirname(probe)
@@ -212,7 +217,7 @@ class HardwareSpec:
             nvme_capacity = 0.0
         kw = dict(n_devices=n, device_mem=float(device_mem),
                   host_mem=host_mem, nvme_capacity=nvme_capacity,
-                  devices_per_node=n, source="detected")
+                  devices_per_node=n, source="detected", **rates)
         kw.update(overrides)
         return cls(**kw)
 

@@ -14,11 +14,11 @@ import dataclasses
 import json
 from typing import Optional
 
-from repro import compat
+from repro.peaks import PEAKS
 from repro.roofline import hlo_parse
 
-PEAK_FLOPS = 197e12  # bf16 / chip (TPU v5e)
-HBM_BW = 819e9  # B/s / chip
+PEAK_FLOPS = PEAKS["TPU v5 lite"].flops  # the dry-run's target chip
+HBM_BW = PEAKS["TPU v5 lite"].hbm_bw
 ICI_LINK_BW = 50e9  # B/s / link (assignment constant)
 ICI_LINKS = 1  # conservative: per-chip collective bandwidth = 1 link
 
@@ -88,7 +88,7 @@ def analyze(compiled, *, arch: str, shape: str, mesh_name: str, n_chips: int,
             model_flops_total: float) -> Roofline:
     costs = hlo_parse.module_costs(compiled.as_text())
     ma = None
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     try:
         ma = compiled.memory_analysis()
     except Exception:

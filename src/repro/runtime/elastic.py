@@ -446,7 +446,8 @@ class ElasticSupervisor:
     def _build(self, plan, dp: int, gen: int):
         import dataclasses as dc
 
-        from repro import compat
+        import jax
+
         from repro.core.executor import InfinityExecutor
 
         # each incarnation streams through its own NVMe namespace: rank-key
@@ -458,10 +459,10 @@ class ElasticSupervisor:
         if self.parallel_kw:
             run = run.replace(
                 parallel=dc.replace(run.parallel, **self.parallel_kw))
-        mesh = compat.make_mesh(
+        mesh = jax.make_mesh(
             (dp, 1), ("data", "model"),
             devices=self.membership.alive_devices()[:dp],
-            axis_types=(compat.AxisType.Auto, compat.AxisType.Auto))
+            axis_types=(jax.sharding.AxisType.Auto,) * 2)
         self._executor = InfinityExecutor(run, mesh, plan=plan)
         return self._executor, mesh, run
 
@@ -547,7 +548,8 @@ class ElasticSupervisor:
 
     def _resume(self, executor, mesh, run, plan, state, start: int,
                 dp: int) -> Optional[_Directive]:
-        from repro import compat
+        import jax
+
         from repro.data.pipeline import PrefetchLoader, SyntheticStream
 
         step_fn = executor.make_train_step()
@@ -578,7 +580,7 @@ class ElasticSupervisor:
                     f"elastic: cumulative recovery {self.stats.recovery_s:.2f}s"
                     f" exceeds the {self.config.recovery_budget_s:.0f}s budget")
         try:
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 for step, batch in loader:
                     directive = self._membership_events(executor, state, step)
                     if directive is not None:

@@ -19,7 +19,7 @@ def make_engine(dp):
     mesh = jax.make_mesh((dp,), ("data",), devices=jax.devices()[:dp], axis_types=auto)
     run = RunConfig(model=configs.smoke("smollm-135m"),
                     parallel=ParallelConfig(zero_stage=3), train=TrainConfig())
-    return ZeroInfinityEngine(run, mesh, host_offload_in_graph=False), mesh
+    return ZeroInfinityEngine(run, mesh), mesh
 
 
 def main():

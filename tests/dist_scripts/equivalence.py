@@ -37,7 +37,7 @@ def batch_for(cfg, shape, seed=0):
 
 def loss_after_steps(cfg, mesh, pc, batch, n=2):
     run = RunConfig(model=cfg, parallel=pc, train=TrainConfig(lr=1e-3))
-    eng = ZeroInfinityEngine(run, mesh, host_offload_in_graph=False)
+    eng = ZeroInfinityEngine(run, mesh)
     state = eng.init_state(jax.random.PRNGKey(42))
     with jax.set_mesh(mesh):
         step = jax.jit(eng.make_train_step())

@@ -1,5 +1,6 @@
-"""Per-kernel allclose sweeps against the ref.py oracles (interpret=True on
-CPU), including hypothesis property tests over shapes."""
+"""Per-kernel allclose sweeps against the ref.py oracles (Pallas interpret
+mode, which kernels/ops.py selects on the CPU), including hypothesis
+property tests over shapes."""
 import jax
 import jax.numpy as jnp
 import numpy as np

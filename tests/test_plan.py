@@ -397,6 +397,33 @@ def test_detect_probes_live_backend(tmp_path):
     assert hw2.device_mem == 123.0 and hw2.n_devices == 7
 
 
+def test_detect_takes_accelerator_rates_from_peaks_table(monkeypatch, tmp_path):
+    """On an accelerator, detect() reads peak FLOP/s, HBM bandwidth and (when
+    the backend reports none) HBM bytes from repro.peaks; a device kind
+    missing from the table raises instead of defaulting."""
+    from repro import peaks
+
+    class _Chip:
+        platform = "tpu"
+
+        def __init__(self, kind):
+            self.device_kind = kind
+
+        def memory_stats(self):
+            return None
+
+    monkeypatch.setattr(jax, "devices", lambda: [_Chip("TPU v5 lite")])
+    hw = HardwareSpec.detect(nvme_dir=str(tmp_path))
+    assert (hw.peak_flops, hw.device_bw, hw.device_mem) == (197e12, 819e9, 16e9)
+    monkeypatch.setattr(jax, "devices", lambda: [_Chip("TPU v99")])
+    with pytest.raises(ValueError, match="TPU v99"):
+        HardwareSpec.detect(nvme_dir=str(tmp_path))
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks.peaks_for(_Chip("TPU v99"))
+    monkeypatch.undo()
+    assert peaks.peaks_for(jax.devices()[0]) is None  # the CPU: no device peaks
+
+
 def test_hardware_spec_validation():
     with pytest.raises(ValueError, match="n_devices"):
         HardwareSpec(n_devices=0)

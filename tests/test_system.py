@@ -59,3 +59,44 @@ def test_serve_cli_paged_nvme(tmp_path):
                    "--kv-dir", str(tmp_path), "--prompt-len", "16",
                    "--new-tokens", "8"])
     assert "kv[nvme]:" in out and "SERVE SMOKE OK" in out
+
+
+def test_chip_smoke_refuses_without_a_tpu():
+    """No CPU fallback: without a TPU the chip smoke exits non-zero at once,
+    names the device it found, and prints no result line."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, os.path.join(ROOT, "chip_smoke.py")],
+                       env=env, capture_output=True, text=True, timeout=120)
+    assert r.returncode != 0
+    assert "needs a TPU" in r.stderr and "cpu device" in r.stderr
+    assert '"ok"' not in r.stdout
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """Without JAX_COMPILATION_CACHE_DIR the cache is the checkout's fixed
+    .jax_cache; with it, JAX's own setting stands and compiles land there."""
+    import jax
+
+    from repro.launch import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        got = compile_cache.enable_compile_cache()
+        assert got == jax.config.jax_compilation_cache_dir
+        assert got == os.path.join(os.path.abspath(ROOT), ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+    code = ("from repro.launch.compile_cache import enable_compile_cache\n"
+            "print(enable_compile_cache())\n"
+            "import jax, jax.numpy as jnp\n"
+            "jax.jit(lambda x: x * 2 + 1)(jnp.ones(8)).block_until_ready()\n")
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"),
+               JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.strip() == str(tmp_path)
+    assert any(tmp_path.iterdir())

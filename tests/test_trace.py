@@ -296,8 +296,11 @@ def test_plan_peak_flops_changes_reported_mfu():
     from repro import plan as plan_mod
     from repro.launch.train import make_metrics_logger
 
+    class _Chip:  # manual mode reads the peaks table by device kind
+        platform, device_kind = "tpu", "TPU v5 lite"
+
     class _Mesh:
-        devices = np.array([object()])
+        devices = np.array([_Chip()])
 
     hw_lo = plan_mod.HardwareSpec(n_devices=1, peak_flops=100e12)
     hw_hi = plan_mod.HardwareSpec(n_devices=2, peak_flops=400e12)
@@ -312,7 +315,8 @@ def test_plan_peak_flops_changes_reported_mfu():
         lg = make_metrics_logger(1e9, _Mesh(), plan)
         lg.log_fn = lambda *_: None
         recs[name] = lg.log(0, 1.0, tokens=4096, dt=0.5)
-    assert recs["manual"]["mfu_est"] > 0
+    assert recs["manual"]["mfu_est"] == pytest.approx(
+        6.0 * 1e9 * 4096 / 0.5 / 197e12, rel=1e-9)
     # 8x the peak-FLOPs pool (100e12 -> 2 x 400e12) -> 1/8 the reported MFU
     assert recs["lo"]["mfu_est"] == pytest.approx(
         8 * recs["hi"]["mfu_est"], rel=1e-9)
