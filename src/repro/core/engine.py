@@ -274,6 +274,7 @@ class ZeroInfinityEngine:
         return self.lower_decode(shape)
 
 
+@jax.named_scope("grad_norm")
 def _global_norm(tree) -> jax.Array:
     leaves = [jnp.sum(jnp.square(x.astype(jnp.float32))) for x in jax.tree.leaves(tree)]
     return jnp.sqrt(sum(leaves))

@@ -480,29 +480,31 @@ class InfinityExecutor:
     # ------------------------------------------------------------------
 
     def make_train_step(self):
-        if self._step_fn is not None:
-            return self._step_fn
+        """The step, numbered and under the profiler's step span
+        (``trace.StepFn``); ``lower`` reaches the jitted step where there
+        is one."""
+        if self._step_fn is None:
+            self._step_fn = trace.StepFn(self._build_train_step())
+        return self._step_fn
+
+    def _build_train_step(self):
         if self.layered:
             # scheduler-driven layered epoch: no monolithic jitted step at
             # all — per-layer fns iterate rows through the prefetch window
-            self._step_fn = (self._layered_moe_step() if self.is_moe
-                             else self._layered_step())
-            return self._step_fn
+            return (self._layered_moe_step() if self.is_moe
+                    else self._layered_step())
         jit_step = self.engine.jit_train_step(grads_only=self.offgraph)
 
         if not self.offgraph and not self.param_nvme:
-            step = jit_step  # fully in-graph (device/host tiers)
+            return jit_step  # fully in-graph (device/host tiers)
+        if not self.offgraph:
+            # GSPMD in-graph update; only params stream (scheduler-fed)
+            inner = jit_step
         else:
-            if not self.offgraph:
-                # GSPMD in-graph update; only params stream (scheduler-fed)
-                inner = jit_step
-            else:
-                inner = (self._explicit_offgraph_step(jit_step)
-                         if self.is_explicit
-                         else self._gspmd_offgraph_step(jit_step))
-            step = self._instrumented(inner)
-        self._step_fn = step
-        return step
+            inner = (self._explicit_offgraph_step(jit_step)
+                     if self.is_explicit
+                     else self._gspmd_offgraph_step(jit_step))
+        return self._instrumented(inner)
 
     def train_step(self, state, batch):
         return self.make_train_step()(state, batch)

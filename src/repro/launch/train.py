@@ -28,7 +28,6 @@ from repro.core.executor import InfinityExecutor
 from repro.data.pipeline import PrefetchLoader, SyntheticStream
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, maybe_init_distributed
-from repro.peaks import peaks_for
 from repro.runtime import trace
 from repro.runtime.elastic import wire_straggler
 from repro.runtime.fault import FailureInjector, StragglerMonitor, retry_loop
@@ -149,18 +148,6 @@ def make_run(args):
     return run, None
 
 
-def make_metrics_logger(model_flops_per_token, mesh, plan) -> MetricsLogger:
-    """MFU denominator: the plan's hardware when one exists, else the
-    mesh's device kind in the peaks table (none on the CPU)."""
-    if plan is not None:
-        peak, n = float(plan.hardware.peak_flops), int(plan.hardware.n_devices)
-    else:
-        chip = peaks_for(mesh.devices.flat[0])
-        peak, n = (chip.flops if chip else None), len(mesh.devices.flat)
-    return MetricsLogger(model_flops_per_token=model_flops_per_token,
-                         peak_flops=peak, n_chips=n)
-
-
 def train_elastic(args) -> dict:
     """The ``--elastic`` path: the ElasticSupervisor owns the loop. Config
     is always plan-derived here (re-planning against the surviving hardware
@@ -244,7 +231,7 @@ def train(args) -> dict:
                                  seed=run.train.seed)
         loader = PrefetchLoader(stream, start_step, run.train.steps,
                                 executor.batch_shardings(shape))
-        logger = make_metrics_logger(executor.n_params_active(), mesh, plan)
+        logger = MetricsLogger()
         tokens = shape.global_batch * shape.seq_len
         metrics = None
 

@@ -44,6 +44,7 @@ def layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array, eps: float = 1e-
     return out.astype(dt)
 
 
+@jax.named_scope("norm")
 def norm(x: jax.Array, p: dict, kind: str) -> jax.Array:
     if kind == "rmsnorm":
         return rms_norm(x, p["scale"])
@@ -236,36 +237,43 @@ def attention_block(
     """
     B, S, _ = x.shape
     xs = kv_source if kv_source is not None else x
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
-    kx = jnp.einsum("bsd,dhk->bshk", xs, p["wk"].astype(x.dtype))
-    vx = jnp.einsum("bsd,dhk->bshk", xs, p["wv"].astype(x.dtype))
-    if kv_source is None:  # self-attention: rope at absolute positions
-        q = rope(q, positions, cfg.rope_theta)
-        kx = rope(kx, positions, cfg.rope_theta)
+    with jax.named_scope("attn/qkv"):
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
+        kx = jnp.einsum("bsd,dhk->bshk", xs, p["wk"].astype(x.dtype))
+        vx = jnp.einsum("bsd,dhk->bshk", xs, p["wv"].astype(x.dtype))
+        if kv_source is None:  # self-attention: rope at absolute positions
+            q = rope(q, positions, cfg.rope_theta)
+            kx = rope(kx, positions, cfg.rope_theta)
 
     new_cache = None
-    if cache is not None:
-        # decode: write the new K/V at the filled-prefix offset (or an
-        # explicit ring position for window-bounded caches)
-        k_cache, v_cache, clen = cache["k"], cache["v"], cache["len"]
-        write_pos = cache.get("write_pos", clen)
-        valid_len = cache.get("valid_len", clen + S)
-        k_cache = _scatter_cache(k_cache, kx, write_pos)
-        v_cache = _scatter_cache(v_cache, vx, write_pos)
-        new_cache = {"k": k_cache, "v": v_cache, "len": clen + S}
-        q = pt.constrain(q, rules, ("batch", None, "act_heads", None))
-        out = decode_attention(q, k_cache, v_cache, valid_len)
-    else:
-        q = pt.constrain(q, rules, ("batch", "seq", "act_heads", None))
-        kx = pt.constrain(kx, rules, ("batch", "kv_seq", None, None))
-        vx = pt.constrain(vx, rules, ("batch", "kv_seq", None, None))
-        out = chunked_attention(q, kx, vx, causal=causal and kv_source is None,
-                                window=window, score_dtype=cfg.score_dtype,
-                                q_chunk=cfg.attn_chunk, kv_chunk=cfg.attn_chunk)
-        if collect_kv:
-            new_cache = {"k": kx.astype(jnp.bfloat16), "v": vx.astype(jnp.bfloat16)}
-    out = jnp.einsum("bshk,hkd->bsd", out.astype(x.dtype), p["wo"].astype(x.dtype))
-    return pt.constrain(out, rules, ("batch", "seq", None)), new_cache
+    with jax.named_scope("attn/core"):
+        if cache is not None:
+            # decode: write the new K/V at the filled-prefix offset (or an
+            # explicit ring position for window-bounded caches)
+            k_cache, v_cache, clen = cache["k"], cache["v"], cache["len"]
+            write_pos = cache.get("write_pos", clen)
+            valid_len = cache.get("valid_len", clen + S)
+            k_cache = _scatter_cache(k_cache, kx, write_pos)
+            v_cache = _scatter_cache(v_cache, vx, write_pos)
+            new_cache = {"k": k_cache, "v": v_cache, "len": clen + S}
+            q = pt.constrain(q, rules, ("batch", None, "act_heads", None))
+            out = decode_attention(q, k_cache, v_cache, valid_len)
+        else:
+            q = pt.constrain(q, rules, ("batch", "seq", "act_heads", None))
+            kx = pt.constrain(kx, rules, ("batch", "kv_seq", None, None))
+            vx = pt.constrain(vx, rules, ("batch", "kv_seq", None, None))
+            out = chunked_attention(q, kx, vx,
+                                    causal=causal and kv_source is None,
+                                    window=window, score_dtype=cfg.score_dtype,
+                                    q_chunk=cfg.attn_chunk,
+                                    kv_chunk=cfg.attn_chunk)
+            if collect_kv:
+                new_cache = {"k": kx.astype(jnp.bfloat16),
+                             "v": vx.astype(jnp.bfloat16)}
+    with jax.named_scope("attn/out"):
+        out = jnp.einsum("bshk,hkd->bsd", out.astype(x.dtype),
+                         p["wo"].astype(x.dtype))
+        return pt.constrain(out, rules, ("batch", "seq", None)), new_cache
 
 
 def _scatter_cache(cache: jax.Array, new: jax.Array, pos) -> jax.Array:
@@ -301,6 +309,7 @@ def mlp_defs(cfg: ModelConfig) -> dict:
     return defs
 
 
+@jax.named_scope("mlp")
 def mlp_block(p: dict, x: jax.Array, cfg: ModelConfig, rules: pt.AxisRules,
               tiling_factor: int = 1) -> jax.Array:
     from repro.core.tiling import tiled_matmul_xla  # local import to avoid cycle
@@ -337,6 +346,7 @@ def embed_defs(cfg: ModelConfig) -> dict:
     return defs
 
 
+@jax.named_scope("embed")
 def embed(p: dict, tokens: jax.Array, cfg: ModelConfig, rules: pt.AxisRules) -> jax.Array:
     x = p["tok"].astype(jnp.bfloat16)[tokens]
     if cfg.arch.startswith("gemma") or cfg.arch.startswith("recurrentgemma"):
@@ -344,6 +354,7 @@ def embed(p: dict, tokens: jax.Array, cfg: ModelConfig, rules: pt.AxisRules) -> 
     return pt.constrain(x, rules, ("batch", "seq", None))
 
 
+@jax.named_scope("head")
 def logits(p: dict, x: jax.Array, cfg: ModelConfig, rules: pt.AxisRules) -> jax.Array:
     if cfg.tie_embeddings:
         out = jnp.einsum("bsd,vd->bsv", x, p["tok"].astype(x.dtype))
@@ -354,6 +365,7 @@ def logits(p: dict, x: jax.Array, cfg: ModelConfig, rules: pt.AxisRules) -> jax.
     return out
 
 
+@jax.named_scope("head")
 def lm_loss(lg: jax.Array, labels: jax.Array, vocab_size: int) -> jax.Array:
     """Cross-entropy over (possibly padded) vocab; labels (B, S) int32."""
     lg = lg.astype(jnp.float32)

@@ -89,6 +89,7 @@ def _capacity(cfg: ModelConfig, T: int) -> int:
     return min(cap, T * cfg.top_k)
 
 
+@jax.named_scope("moe/router")
 def route_tokens(router: jax.Array, xg: jax.Array, cfg: ModelConfig) -> dict:
     """Sorted-dispatch routing plan. xg: (G, T, d) grouped tokens.
 
@@ -132,6 +133,7 @@ def route_tokens(router: jax.Array, xg: jax.Array, cfg: ModelConfig) -> dict:
             "counts": counts, "cap": cap}
 
 
+@jax.named_scope("moe/router")
 def routing_stats(counts: jax.Array, cap: int, k: int) -> dict:
     """counts (G, E) -> the S1 drop/load accounting.
 
@@ -147,6 +149,7 @@ def routing_stats(counts: jax.Array, cap: int, k: int) -> dict:
             "moe_expert_load": load}
 
 
+@jax.named_scope("moe/experts")
 def expert_mix(xin: jax.Array, w_in: jax.Array, w_out: jax.Array,
                w_gate, mlp_kind: str) -> jax.Array:
     """(G, E', C, d) x per-expert weights (E', d, f)/(E', f, d) -> (G, E', C, d).
@@ -180,22 +183,24 @@ def moe_ffn(p: dict, x: jax.Array, cfg: ModelConfig, rules: pt.AxisRules,
     tok_ec, valid_ec, w_ec = r["tok_ec"], r["valid_ec"], r["w_ec"]
 
     gidx = jnp.arange(G)[:, None, None]
-    xin = xg[gidx, tok_ec]  # (G,E,C,d) gather; rank-local w/ model-replicated xg
-    xin = jnp.where(valid_ec[..., None], xin, 0)
-    xin = pt.constrain(xin, rules, ("batch", "experts", None, None))
+    with jax.named_scope("moe/dispatch"):
+        xin = xg[gidx, tok_ec]  # (G,E,C,d) gather; rank-local w/ model-replicated xg
+        xin = jnp.where(valid_ec[..., None], xin, 0)
+        xin = pt.constrain(xin, rules, ("batch", "experts", None, None))
 
     out = expert_mix(xin, p["w_in"], p["w_out"], p.get("w_gate"), cfg.mlp_kind)
-    out = out * w_ec[..., None].astype(out.dtype)
 
     # token-major combine: scatter-add back to token order; the cross-expert
     # reduction lowers to the model-axis psum. A gather-based inverse combine
     # was tried and MEASURED (EXPERIMENTS.md §Perf llama4 it-3): neutral for
     # top-1 (llama4) but 4x worse collectives for top-8 (granite) — its
     # backward re-scatters per k. Scatter-add kept as the default.
-    cdt = jnp.dtype(cfg.moe_combine_dtype)
-    y = jnp.zeros(xg.shape, cdt).at[gidx, tok_ec].add(out.astype(cdt))
-    y = pt.constrain(y, rules, ("batch", None, None))
-    y = y.astype(x.dtype).reshape(B, S, d)
+    with jax.named_scope("moe/combine"):
+        out = out * w_ec[..., None].astype(out.dtype)
+        cdt = jnp.dtype(cfg.moe_combine_dtype)
+        y = jnp.zeros(xg.shape, cdt).at[gidx, tok_ec].add(out.astype(cdt))
+        y = pt.constrain(y, rules, ("batch", None, None))
+        y = y.astype(x.dtype).reshape(B, S, d)
     if with_stats:
         return y, routing_stats(r["counts"], r["cap"], cfg.top_k)
     return y
@@ -236,18 +241,20 @@ def moe_ffn_selected(router: jax.Array, rows: dict, x: jax.Array,
     w_sel = jnp.take(r["w_ec"], sel_ids, axis=1) * sel_mask[None, :, None]
 
     gidx = jnp.arange(G)[:, None, None]
-    xin = xg[gidx, tok_sel]
-    xin = jnp.where(valid_sel[..., None], xin, 0)
-    xin = pt.constrain(xin, rules, ("batch", "experts", None, None))
+    with jax.named_scope("moe/dispatch"):
+        xin = xg[gidx, tok_sel]
+        xin = jnp.where(valid_sel[..., None], xin, 0)
+        xin = pt.constrain(xin, rules, ("batch", "experts", None, None))
 
     out = expert_mix(xin, rows["w_in"], rows["w_out"], rows.get("w_gate"),
                      cfg.mlp_kind)
-    out = out * w_sel[..., None].astype(out.dtype)
 
-    cdt = jnp.dtype(cfg.moe_combine_dtype)
-    y = jnp.zeros(xg.shape, cdt).at[gidx, tok_sel].add(out.astype(cdt))
-    y = pt.constrain(y, rules, ("batch", None, None))
-    return y.astype(x.dtype).reshape(B, S, d)
+    with jax.named_scope("moe/combine"):
+        out = out * w_sel[..., None].astype(out.dtype)
+        cdt = jnp.dtype(cfg.moe_combine_dtype)
+        y = jnp.zeros(xg.shape, cdt).at[gidx, tok_sel].add(out.astype(cdt))
+        y = pt.constrain(y, rules, ("batch", None, None))
+        return y.astype(x.dtype).reshape(B, S, d)
 
 
 def make_fns(cfg: ModelConfig, rules: pt.AxisRules, parallel: ParallelConfig):
@@ -275,7 +282,8 @@ def make_fns(cfg: ModelConfig, rules: pt.AxisRules, parallel: ParallelConfig):
 
         if parallel.remat != "none":
             body = jax.checkpoint(body, policy=policy, prevent_cse=False)
-        x, stats = jax.lax.scan(body, x, params["blocks"])
+        with jax.named_scope("layers"):
+            x, stats = jax.lax.scan(body, x, params["blocks"])
         return x, stats  # stats leaves carry a leading (L,) layer axis
 
     def loss_stats_fn(params, batch):
@@ -286,11 +294,15 @@ def make_fns(cfg: ModelConfig, rules: pt.AxisRules, parallel: ParallelConfig):
         x, stats = run_blocks(params, x, positions)
         x = cm.norm(x, params["ln_f"], cfg.norm_kind)
         lg = cm.logits(params["embed"], x, cfg, rules)
-        loss = cm.lm_loss(lg[:, :-1], batch["labels"][:, 1:], cfg.vocab_size)
+        with jax.named_scope("head"):  # the shift, and its pad backward
+            loss = cm.lm_loss(lg[:, :-1], batch["labels"][:, 1:],
+                              cfg.vocab_size)
         # reduce over layers: scalar drop fraction + (E,) mean load
-        aux = {"moe_dropped_token_fraction":
-                   jnp.mean(stats["moe_dropped_token_fraction"]),
-               "moe_expert_load": jnp.mean(stats["moe_expert_load"], axis=0)}
+        with jax.named_scope("moe/router"):
+            aux = {"moe_dropped_token_fraction":
+                       jnp.mean(stats["moe_dropped_token_fraction"]),
+                   "moe_expert_load": jnp.mean(stats["moe_expert_load"],
+                                               axis=0)}
         return loss, aux
 
     def loss_fn(params, batch):
@@ -308,7 +320,8 @@ def make_fns(cfg: ModelConfig, rules: pt.AxisRules, parallel: ParallelConfig):
 
         if parallel.remat != "none":
             body = jax.checkpoint(body, policy=policy, prevent_cse=False)
-        x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
+        with jax.named_scope("layers"):
+            x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
         x = cm.norm(x, params["ln_f"], cfg.norm_kind)
         lg = cm.logits(params["embed"], x[:, -1:], cfg, rules)
         return lg, {"k": ks, "v": vs, "len": jnp.asarray(S, jnp.int32)}
@@ -326,7 +339,9 @@ def make_fns(cfg: ModelConfig, rules: pt.AxisRules, parallel: ParallelConfig):
             out, nc = block(h, blk, positions, cache={"k": kc, "v": vc, "len": clen})
             return out, (nc["k"], nc["v"])
 
-        x, (ks, vs) = jax.lax.scan(body, x, (params["blocks"], cache["k"], cache["v"]))
+        with jax.named_scope("layers"):
+            x, (ks, vs) = jax.lax.scan(
+                body, x, (params["blocks"], cache["k"], cache["v"]))
         x = cm.norm(x, params["ln_f"], cfg.norm_kind)
         lg = cm.logits(params["embed"], x, cfg, rules)
         return lg, {"k": ks, "v": vs, "len": clen + 1}

@@ -96,7 +96,8 @@ def make_fns(cfg: ModelConfig, rules: pt.AxisRules, parallel: ParallelConfig):
 
         if parallel.remat != "none":
             body = jax.checkpoint(body, policy=policy, prevent_cse=False)
-        x, _ = jax.lax.scan(body, x, params["blocks"])
+        with jax.named_scope("layers"):
+            x, _ = jax.lax.scan(body, x, params["blocks"])
         return x
 
     def backbone_inputs(params, batch):
@@ -116,9 +117,10 @@ def make_fns(cfg: ModelConfig, rules: pt.AxisRules, parallel: ParallelConfig):
         x = cm.norm(x, params["ln_f"], cfg.norm_kind)
         lg = cm.logits(params["embed"], x, cfg, rules)
         labels = batch["labels"]
-        if cfg.family == "vlm":  # loss only on text positions
-            lg = lg[:, cfg.vision_len :]
-        return cm.lm_loss(lg[:, :-1], labels[:, 1:], cfg.vocab_size)
+        with jax.named_scope("head"):  # the shift, and its pad backward
+            if cfg.family == "vlm":  # loss only on text positions
+                lg = lg[:, cfg.vision_len :]
+            return cm.lm_loss(lg[:, :-1], labels[:, 1:], cfg.vocab_size)
 
     # ----------------------------- serving --------------------------------
 
@@ -143,7 +145,8 @@ def make_fns(cfg: ModelConfig, rules: pt.AxisRules, parallel: ParallelConfig):
 
         if parallel.remat != "none":
             body = jax.checkpoint(body, policy=policy, prevent_cse=False)
-        x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
+        with jax.named_scope("layers"):
+            x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
         x = cm.norm(x, params["ln_f"], cfg.norm_kind)
         lg = cm.logits(params["embed"], x[:, -1:], cfg, rules)
         cache = {"k": ks, "v": vs, "len": jnp.asarray(S, jnp.int32)}
@@ -164,7 +167,9 @@ def make_fns(cfg: ModelConfig, rules: pt.AxisRules, parallel: ParallelConfig):
             out, new_cache = block(h, blk, positions, cache={"k": kc, "v": vc, "len": clen})
             return out, (new_cache["k"], new_cache["v"])
 
-        x, (ks, vs) = jax.lax.scan(body, x, (params["blocks"], cache["k"], cache["v"]))
+        with jax.named_scope("layers"):
+            x, (ks, vs) = jax.lax.scan(
+                body, x, (params["blocks"], cache["k"], cache["v"]))
         x = cm.norm(x, params["ln_f"], cfg.norm_kind)
         lg = cm.logits(params["embed"], x, cfg, rules)
         return lg, {"k": ks, "v": vs, "len": clen + 1}

@@ -51,6 +51,7 @@ def lr_at(tc: TrainConfig, step: jax.Array) -> jax.Array:
     return tc.lr * warm
 
 
+@jax.named_scope("optimizer")
 def apply_updates(grads, state: AdamState, tc: TrainConfig, *, params_prev=None,
                   use_fused: bool = False):
     """Returns (new compute-dtype params, new AdamState). grads: bf16/f32 tree.

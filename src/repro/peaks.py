@@ -1,8 +1,8 @@
 """Published per-chip peaks, keyed by the ``device_kind`` JAX reports.
 
 The one place that says how fast a chip is: the planner's
-``HardwareSpec.detect``, the MFU denominator of ``MetricsLogger`` and the
-dry-run roofline read their figures from here. An accelerator that is not
+``HardwareSpec.detect`` and the dry-run roofline read their figures from
+here. An accelerator that is not
 in the table is an error, never a default; the CPU has no entry because no
 device metric is taken on it.
 """

@@ -557,10 +557,7 @@ class ElasticSupervisor:
                                  run.model.vocab_size, seed=self.train.seed)
         loader = PrefetchLoader(stream, start, self.train.steps,
                                 executor.batch_shardings(self.shape))
-        logger = MetricsLogger(
-            model_flops_per_token=executor.n_params_active(),
-            peak_flops=float(plan.hardware.peak_flops),
-            n_chips=int(plan.hardware.n_devices), log_fn=self.log)
+        logger = MetricsLogger(log_fn=self.log)
         tokens = self.shape.global_batch * self.shape.seq_len
         self.stats.n_alive = self.membership.n_alive
         self.stats.membership_version = self.membership.version

@@ -1,19 +1,12 @@
-"""Step metrics: tokens/s, step-time EMA, analytic MFU estimate, and the
-serving-side KV-tier counters (``kv_*`` fields)."""
+"""Step metrics: tokens/s, step-time EMA, and the serving-side KV-tier
+counters (``kv_*`` fields)."""
 from __future__ import annotations
 
-import time
 from typing import Optional
 
 
 class MetricsLogger:
-    """``peak_flops`` is one chip's peak (``repro.peaks``); None, as on the
-    CPU, leaves ``mfu_est`` unset."""
-
-    def __init__(self, model_flops_per_token: float, peak_flops: Optional[float],
-                 n_chips: int = 1, log_fn=print):
-        self.fpt = model_flops_per_token
-        self.peak = None if peak_flops is None else peak_flops * n_chips
+    def __init__(self, log_fn=print):
         self.log_fn = log_fn
         self.ema: Optional[float] = None
         self.history = []
@@ -21,11 +14,8 @@ class MetricsLogger:
     def log(self, step: int, loss: float, tokens: int, dt: float, **kw) -> dict:
         self.ema = dt if self.ema is None else 0.9 * self.ema + 0.1 * dt
         tps = tokens / dt if dt > 0 else 0.0
-        mfu = None
-        if self.peak is not None:
-            mfu = 6.0 * self.fpt * tps / self.peak if self.fpt else 0.0
         rec = {"step": step, "loss": float(loss), "tokens_per_s": tps,
-               "step_time": dt, "step_time_ema": self.ema, "mfu_est": mfu, **kw}
+               "step_time": dt, "step_time_ema": self.ema, **kw}
         self.history.append(rec)
         self.log_fn(
             f"step {step:5d} | loss {loss:8.4f} | {tps:9.0f} tok/s | "

@@ -28,6 +28,13 @@ measurement side of that question:
     under compute — and the Eq.-6-style measured efficiency
     ``compute_s / (compute_s + io_wait_s)`` to print beside the plan's
     prediction.
+  * the profiler's clock — while enabled, every span, instant and ``wrap``
+    also opens a ``jax.profiler.TraceAnnotation`` of the same name, tagged
+    with its ``sys``/``cls``/``unit``, so a live ``jax.profiler`` session
+    records each program span, on every thread, in the host plane of its
+    ``.xplane.pb``, on the device trace's clock. ``StepFn`` numbers the
+    train step's calls and, while enabled, opens a ``StepTraceAnnotation``
+    around each, a host marker per step that carries its ``step_num``.
   * exports — Chrome/Perfetto trace-event JSON (``export_chrome``: one
     track per thread with matched B/E pairs, one counter track per class
     with cumulative wire bytes) and a compact text stall report
@@ -50,6 +57,8 @@ import threading
 import time
 from collections import deque
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 # Subsystem tags (the ``sys=`` span arg). Kept as a tuple so gates can
 # report coverage ("spans from >= 4 distinct subsystems") by one name.
@@ -81,7 +90,7 @@ class _Span:
     only known mid-span (bytes read, hit/miss)."""
 
     __slots__ = ("_tr", "name", "sys", "cls", "attr", "unit", "args",
-                 "_t0", "_s0")
+                 "_t0", "_s0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, sys_: Optional[str],
                  cls: Optional[str], attr: Optional[str], unit, args: dict):
@@ -94,12 +103,15 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        self._ann = _annotation(self.name, self.sys, self.cls, self.unit)
+        self._ann.__enter__()
         self._s0 = next(self._tr._seq)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
         tr = self._tr
         th = threading.current_thread()
         tr._buf.append((self.name, self.sys, self.cls, self.attr, self.unit,
@@ -109,6 +121,39 @@ class _Span:
 
     def set(self, **kw) -> None:
         self.args.update(kw)
+
+
+def _annotation(name: str, sys_, cls, unit) -> TraceAnnotation:
+    """The profiler's host event for a span: its name, and its tags as the
+    event's metadata."""
+    tags = {k: str(v) for k, v in (("sys", sys_), ("cls", cls),
+                                   ("unit", unit)) if v is not None}
+    return TraceAnnotation(name, **tags)
+
+
+STEP_SPAN = "train_step"
+
+
+class StepFn:
+    """A train step that numbers its calls and, while the Tracer is
+    enabled, opens ``StepTraceAnnotation(STEP_SPAN, step_num=n)`` around
+    each; disabled, a call costs one attribute check. It never waits for
+    the device: a jitted step returns on dispatch as before. Other
+    attributes (``lower`` of a jitted step) are the wrapped step's."""
+
+    def __init__(self, fn: Callable):
+        self.fn = fn
+        self.step_num = 0
+
+    def __call__(self, *args, **kw):
+        n, self.step_num = self.step_num, self.step_num + 1
+        if not TRACER.enabled:
+            return self.fn(*args, **kw)
+        with StepTraceAnnotation(STEP_SPAN, step_num=n):
+            return self.fn(*args, **kw)
+
+    def __getattr__(self, attr):
+        return getattr(self.fn, attr)
 
 
 class Tracer:
@@ -158,7 +203,8 @@ class Tracer:
         """Zero-duration marker event (Chrome ``i`` phase)."""
         if not self._enabled:
             return
-        t = time.perf_counter()
+        with _annotation(name, sys, cls, unit):
+            t = time.perf_counter()
         th = threading.current_thread()
         s = next(self._seq)
         self._buf.append((name, sys, cls, None, unit, t, t, s, s,

@@ -89,19 +89,46 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_host_tier_train_step_compiles_for_v5e(topo):
+@pytest.fixture(scope="module")
+def host_step(topo):
     """The full-width pjit step with the optimizer state in pinned host
-    memory: the engine reads the host memory kind from its mesh, so a mesh
-    of described v5e devices selects ``pinned_host``."""
+    memory, compiled for one described v5e chip: (engine, compiled)."""
     mesh = jax.make_mesh((1, 1), ("data", "model"),
                          devices=topo.devices[:1],
                          axis_types=(jax.sharding.AxisType.Auto,) * 2)
     run = RunConfig(model=CFG, offload=make_offload(opt_tier="host"))
     eng = ZeroInfinityEngine(run, mesh)
+    return eng, eng.lower_train(ShapeConfig("t", SEQ, BATCH, "train")).compile()
+
+
+def test_host_tier_train_step_compiles_for_v5e(host_step):
+    """The engine reads the host memory kind from its mesh, so a mesh of
+    described v5e devices selects ``pinned_host``."""
+    eng, compiled = host_step
     opt_kinds = {s.memory_kind for s in jax.tree.leaves(eng.opt_shardings())}
     assert opt_kinds == {"pinned_host"}
-    compiled = eng.lower_train(ShapeConfig("t", SEQ, BATCH, "train")).compile()
     ma = compiled.memory_analysis()
     # the fp32 master/m/v (12 B/param) are host arguments, not HBM ones
     assert ma.host_argument_size_in_bytes > 12 * 100e6
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 16e9
+
+
+def test_every_op_of_the_v5e_step_is_in_a_model_region(host_step):
+    """On the TPU every fusion, dot and convolution of the step carries the
+    name of a model region (``jax.named_scope``), and the copies between
+    pinned host memory and HBM, which carry no name, are the ``offload``
+    region by their memory space."""
+    from perfbench import regions
+
+    text = host_step[1].as_text()
+    rmap = regions.region_map(text)
+    tops = regions.top_level_ops(text)
+    assert len(tops) > 100
+    assert [t for t in tops if rmap[t][0] == regions.OTHER] == []
+    assert {r for r, _ in map(rmap.get, tops)} >= set(regions.REGIONS) - {
+        "moe/router", "moe/dispatch", "moe/experts", "moe/combine",
+        "offload"}
+    host_copies = [t for t in regions.top_level_ops(text, ("copy-start",))
+                   if "S(5)" in text.split(f"%{t} = ", 1)[1].split(" ", 1)[0]]
+    assert host_copies
+    assert {rmap[t][0] for t in host_copies} == {"offload"}

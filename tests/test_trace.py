@@ -2,8 +2,8 @@
 thread safety under a real ``PrefetchEngine`` worker pool, the
 attribution-sums-to-wall invariant (property-tested where hypothesis is
 installed), the disabled-tracer zero-allocation fast path, Chrome/Perfetto
-export schema validity, and the plan-provided MFU denominator wiring in
-``launch/train.py`` (satellite of the same PR)."""
+export schema validity, and spans and the numbered step span on the
+``jax.profiler`` clock."""
 import json
 import threading
 import time
@@ -288,39 +288,111 @@ def test_chrome_export_survives_ring_eviction(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# satellite (a): the MFU denominator honors the plan's hardware spec
+# the profiler's clock: spans and the step span in a jax.profiler trace
 # ---------------------------------------------------------------------------
 
 
-def test_plan_peak_flops_changes_reported_mfu():
-    from repro import plan as plan_mod
-    from repro.launch.train import make_metrics_logger
+def _host_events(run, tmp_path) -> dict:
+    """Host-plane events recorded while ``run()`` runs under a CPU
+    ``jax.profiler`` session: name -> [stats dict, ...]."""
+    import jax
+    from jax.profiler import ProfileData
 
-    class _Chip:  # manual mode reads the peaks table by device kind
-        platform, device_kind = "tpu", "TPU v5 lite"
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        run()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    out = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host"):
+            for line in plane.lines:
+                for ev in line.events:
+                    out.setdefault(ev.name, []).append(dict(ev.stats))
+    return out
 
-    class _Mesh:
-        devices = np.array([_Chip()])
 
-    hw_lo = plan_mod.HardwareSpec(n_devices=1, peak_flops=100e12)
-    hw_hi = plan_mod.HardwareSpec(n_devices=2, peak_flops=400e12)
+def test_enabled_spans_land_in_the_profiler_host_plane(tracer, tmp_path):
+    def run():
+        with tracer.span("nvme_read", sys="store", cls="param", unit=3):
+            pass
+        tracer.instant("evict", sys="sched")
+        tracer.wrap("jit_piece", lambda: None)()
+        worker = threading.Thread(
+            target=lambda: tracer.span("worker_io", sys="store").__enter__()
+            .__exit__(None, None, None))
+        worker.start()
+        worker.join(timeout=10)
+        assert not worker.is_alive()
 
-    class _Plan:
-        def __init__(self, hw):
-            self.hardware = hw
+    events = _host_events(run, tmp_path)
+    for name in ("nvme_read", "evict", "jit_piece", "worker_io"):
+        assert name in events, sorted(events)
+    stats = events["nvme_read"][0]
+    assert (stats["sys"], stats["cls"], str(stats["unit"])) == (
+        "store", "param", "3")
+    # the ring buffer still records every span for attribution and export
+    assert tracer.span_names() == {"nvme_read": 1, "evict": 1,
+                                   "jit_piece": 1, "worker_io": 1}
 
-    recs = {}
-    for name, plan in [("manual", None), ("lo", _Plan(hw_lo)),
-                       ("hi", _Plan(hw_hi))]:
-        lg = make_metrics_logger(1e9, _Mesh(), plan)
-        lg.log_fn = lambda *_: None
-        recs[name] = lg.log(0, 1.0, tokens=4096, dt=0.5)
-    assert recs["manual"]["mfu_est"] == pytest.approx(
-        6.0 * 1e9 * 4096 / 0.5 / 197e12, rel=1e-9)
-    # 8x the peak-FLOPs pool (100e12 -> 2 x 400e12) -> 1/8 the reported MFU
-    assert recs["lo"]["mfu_est"] == pytest.approx(
-        8 * recs["hi"]["mfu_est"], rel=1e-9)
-    assert recs["lo"]["mfu_est"] != recs["manual"]["mfu_est"]
+
+def test_disabled_tracer_puts_nothing_in_the_profiler(tmp_path):
+    t = Tracer()
+    events = _host_events(lambda: t.span("quiet", sys="store").__enter__(),
+                          tmp_path)
+    assert "quiet" not in events
+
+
+def test_step_fn_numbers_steps_and_keeps_lower(tmp_path, monkeypatch,
+                                               tracer):
+    import jax
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(trace, "TRACER", tracer)
+    tracer.disable()
+    jitted = jax.jit(lambda x: x + 1)
+    step = trace.StepFn(jitted)
+    x = jnp.zeros(4)
+
+    def run():
+        step(x)  # the Tracer is off: numbered, no step span
+        tracer.enable()
+        for _ in range(2):
+            step(x)
+        tracer.disable()
+        step(x)
+
+    events = _host_events(run, tmp_path)
+    assert step.step_num == 4
+    assert sorted(int(s["step_num"]) for s in events[trace.STEP_SPAN]) == [
+        1, 2]
+    assert "add" in step.lower(x).as_text()  # the jitted step's own lower
+
+
+def test_executor_step_is_one_numbered_step_fn():
+    import dataclasses
+
+    import jax
+
+    from repro import configs
+    from repro.config import RunConfig, TrainConfig, make_offload, make_parallel
+    from repro.core.executor import InfinityExecutor
+    from repro.launch.mesh import make_local_mesh
+
+    cfg = dataclasses.replace(configs.smoke("smollm-135m"), n_layers=1)
+    run = RunConfig(model=cfg, parallel=make_parallel("pjit", remat="none"),
+                    offload=make_offload(opt_tier="device"),
+                    train=TrainConfig())
+    ex = InfinityExecutor(run, make_local_mesh(1, 1))
+    step = ex.make_train_step()
+    assert isinstance(step, trace.StepFn) and ex.make_train_step() is step
+    state = ex.init_state(jax.random.PRNGKey(0))
+    toks = jax.numpy.zeros((2, 16), jax.numpy.int32)
+    batch = {"tokens": toks, "labels": toks}
+    state, metrics = step(state, batch)
+    assert step.step_num == 1 and metrics["loss"].shape == ()
+    assert "jit_train_step" in step.lower(state, batch).as_text()
 
 
 # ---------------------------------------------------------------------------
