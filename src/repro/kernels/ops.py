@@ -1,8 +1,11 @@
 """jit'd public wrappers around the Pallas kernels.
 
 The kernels run compiled on an accelerator. They run in Pallas interpret
-mode only when the default backend is the CPU, which has no Mosaic
+mode only when the program is traced for the CPU, which has no Mosaic
 lowering: that is how the CPU tests check them against ``kernels/ref.py``.
+The target is the device kind of the mesh the program is traced under
+(``jax.set_mesh``), so a step traced for described, compile-only TPU
+devices gets the TPU's kernels; without a mesh, the default device's.
 """
 from __future__ import annotations
 
@@ -16,8 +19,18 @@ from repro.kernels import tiled_matmul as _mm
 LANE = _ad.LANE
 
 
+def _target_kind() -> str:
+    dev = jax.sharding.get_abstract_mesh().abstract_device
+    return dev.device_kind if dev is not None else jax.devices()[0].device_kind
+
+
+def on_tpu() -> bool:
+    """Whether the program being traced compiles for a TPU."""
+    return _target_kind().startswith("TPU")
+
+
 def _interpret() -> bool:
-    return jax.default_backend() == "cpu"
+    return _target_kind() == "cpu"
 
 
 def fused_adam(p32, g32, m, v, *, lr, beta1, beta2, eps, weight_decay, bc1, bc2,
@@ -60,5 +73,6 @@ def quantized_matmul(x, q, scales, **kw):
 
 
 def flash_attention(q, k, v, *, causal=True, **kw):
+    """Differentiable causal GQA flash attention, (B, H, S, D) layout."""
     return _fa.flash_attention(q, k, v, causal=causal, interpret=_interpret(),
                                **kw)

@@ -43,6 +43,7 @@ from repro.core import model_math
 from repro.core.engine import ZeroInfinityEngine
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
+from repro.models import common as cm
 from repro.models import registry
 from repro.roofline import analysis
 from repro.runtime import trace
@@ -110,22 +111,23 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, *,
         rec["plan"] = json.loads(plan.to_json())
     t0 = time.time()
     try:
-        if parallel.engine == "zero3":
-            from repro.core.zero import ExplicitZero3Engine
+        with cm.attention_paths() as tally:  # the step trace's calls
+            if parallel.engine == "zero3":
+                from repro.core.zero import ExplicitZero3Engine
 
-            zeng = ExplicitZero3Engine(run, mesh)
-            if shape.kind != "train":
-                raise ValueError("explicit zero3 engine: train shapes only")
-            lowered = zeng.lower_train(shape)
+                zeng = ExplicitZero3Engine(run, mesh)
+                if shape.kind != "train":
+                    raise ValueError("explicit zero3 engine: train shapes only")
+                lowered = zeng.lower_train(shape)
 
-            class _B:  # bundle stand-in for flops accounting
-                pass
+                class _B:  # bundle stand-in for flops accounting
+                    pass
 
-            eng = _B()
-            eng.bundle = __import__("repro.models.registry", fromlist=["registry"]).build(cfg)
-        else:
-            eng = ZeroInfinityEngine(run, mesh)
-            lowered = eng.lower(shape)
+                eng = _B()
+                eng.bundle = __import__("repro.models.registry", fromlist=["registry"]).build(cfg)
+            else:
+                eng = ZeroInfinityEngine(run, mesh)
+                lowered = eng.lower(shape)
         t_lower = time.time() - t0
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
@@ -143,7 +145,8 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, *,
                    cost_analysis={k: float(v) for k, v in
                                   cost.items()
                                   if isinstance(v, (int, float))},
-                   roofline=roof.to_dict())
+                   roofline=roof.to_dict(),
+                   attention_paths=dict(tally))
     except Exception as e:  # record the failure — these are bugs to fix
         rec.update(status="error", error=f"{type(e).__name__}: {e}",
                    traceback=traceback.format_exc()[-2000:])
@@ -528,6 +531,7 @@ def main() -> None:
                     extra = (f"flops/chip={r['flops']:.3e} "
                              f"bottleneck={r['bottleneck']} "
                              f"roofline={r['roofline_fraction']:.3f} "
+                             f"attention={rec.get('attention_paths')} "
                              f"[{rec['wall_s']:.0f}s]")
                 elif st == "error":
                     extra = rec["error"][:120]

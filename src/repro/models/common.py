@@ -3,13 +3,18 @@ MLP variants, embeddings.
 
 Pure-jnp, sharding-agnostic math; distribution enters only through
 ``partition.constrain`` annotations so the same code runs on 1 CPU device
-(smoke tests) and on the 512-chip production mesh (dry-run). Attention is
-written chunked (online softmax over KV blocks) so peak activation memory is
-O(chunk^2) not O(seq^2) — the XLA-level analogue of the Pallas flash kernel in
-``kernels/flash_attention.py`` (which is the TPU perf path).
+(smoke tests) and on the 512-chip production mesh (dry-run). Causal
+self-attention in training and prefill runs through the Pallas flash kernel
+(``kernels/flash_attention.py``, its own backward) where the program compiles
+for a TPU and the kernel computes exactly the same maths (``flash_path``);
+everything else runs ``chunked_attention``, online softmax over KV blocks, so
+peak activation memory is O(chunk^2) not O(seq^2).
 """
 from __future__ import annotations
 
+import collections
+import contextlib
+import contextvars
 import dataclasses
 from functools import partial
 from typing import Optional
@@ -19,6 +24,8 @@ import jax.numpy as jnp
 
 from repro.config import ModelConfig
 from repro.core import partition as pt
+from repro.kernels import flash_attention as fa
+from repro.kernels import ops
 
 NEG_INF = -1e30
 
@@ -65,19 +72,28 @@ def norm_defs(d: int, kind: str) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         heads_first: bool = False) -> jax.Array:
+    """x: (..., seq, heads, head_dim), or (..., heads, seq, head_dim) when
+    ``heads_first``; positions: (..., seq).
+
+    Rotates the halves of each head: ``[x1 cos - x2 sin, x2 cos + x1 sin]``,
+    written ``x * [cos, cos] + (x @ R) * [sin, sin]`` with R the signed swap
+    of the halves, so that no op writes a half-width (lane-sparse) tensor;
+    the products and sums are the same f32 operations."""
     head_dim = x.shape[-1]
     half = head_dim // 2
     freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     angles = positions[..., :, None].astype(jnp.float32) * freq  # (..., seq, half)
-    angles = angles[..., :, None, :]  # broadcast over heads
-    cos, sin = jnp.cos(angles), jnp.sin(angles)
-    x1, x2 = x[..., :half], x[..., half:]
-    out = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-    )
-    return out.astype(x.dtype)
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    angles = jnp.expand_dims(angles, -3 if heads_first else -2)  # over heads
+    eye = jnp.eye(half, dtype=x.dtype)
+    zero = jnp.zeros_like(eye)
+    swap = jnp.block([[zero, eye], [-eye, zero]])  # [x1, x2] -> [-x2, x1]
+    rotated = jnp.einsum("...d,de->...e", x, swap,
+                         precision=jax.lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
+    return (x * jnp.cos(angles) + rotated * jnp.sin(angles)).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -217,6 +233,68 @@ def decode_attention(
     return o.reshape(B, 1, H, D).astype(q.dtype)
 
 
+_PATHS: contextvars.ContextVar = contextvars.ContextVar("attention_paths",
+                                                        default=None)
+
+
+@contextlib.contextmanager
+def attention_paths():
+    """A tally, by path (``flash``, ``chunked``, ``decode``), of the
+    attention calls traced while it is open: how often the flash kernel
+    engages in a configuration. A layer ``scan`` traces its body once, and
+    a jit cache hit traces nothing."""
+    tally = collections.Counter()
+    token = _PATHS.set(tally)
+    try:
+        yield tally
+    finally:
+        _PATHS.reset(token)
+
+
+def _count(path: str) -> None:
+    tally = _PATHS.get()
+    if tally is not None:
+        tally[path] += 1
+
+
+def flash_path(q_shape, k_shape, rules: pt.AxisRules, *, causal: bool,
+               window: int, softcap: float = 0.0):
+    """The flash kernel as an attention function of heads-first
+    (B, H, S, D) arrays, for q and k of the (B, S, H, D) shapes given, where
+    it computes exactly what ``chunked_attention`` would; else None.
+
+    It takes causal self-attention with no window and no softcap (that of
+    ``chunked_attention``; ``attention_block`` applies none), over a
+    sequence of whole blocks, in a program compiled for a TPU, with the
+    sequence unsharded. On a mesh of several devices it runs under
+    ``shard_map`` over the batch (and head) axes, so that GSPMD never
+    partitions the kernel's custom call."""
+    S = q_shape[1]
+    if not (causal and window == 0 and softcap == 0.0 and k_shape[1] == S
+            and S % fa.LANES == 0 and ops.on_tpu()):
+        return None
+    axes = ("batch", "seq", "act_heads", None)
+    q_spec = tuple(rules.spec(axes, q_shape)) + (None,) * 4
+    kv_spec = tuple(rules.spec(axes, k_shape)) + (None,) * 4
+    if q_spec[1] is not None or q_spec[2] != kv_spec[2]:
+        return None  # context-parallel, or kv heads split unlike q heads
+
+    def run(q, k, v):
+        return ops.flash_attention(q, k, v, causal=True)
+
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return run if jax.device_count() == 1 else None
+    if mesh.size == 1 or mesh.are_all_axes_manual:
+        return run  # one device, or already inside a shard_map
+    if mesh.manual_axes:
+        return None
+    q_p = jax.P(q_spec[0], q_spec[2], None, None)
+    kv_p = jax.P(kv_spec[0], kv_spec[2], None, None)
+    return jax.shard_map(run, mesh=mesh, in_specs=(q_p, kv_p, kv_p),
+                         out_specs=q_p, check_vma=False)
+
+
 def attention_block(
     p: dict,
     x: jax.Array,  # (B, S, d_model)
@@ -237,13 +315,21 @@ def attention_block(
     """
     B, S, _ = x.shape
     xs = kv_source if kv_source is not None else x
+    H, KV, D = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    flash = None
+    if cache is None and kv_source is None:
+        flash = flash_path((B, S, H, D), (B, S, KV, D), rules,
+                           causal=causal, window=window)
+    # the kernel takes heads-first operands: project straight into them
+    heads_first = flash is not None
+    lay = "bhsk" if heads_first else "bshk"
     with jax.named_scope("attn/qkv"):
-        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
-        kx = jnp.einsum("bsd,dhk->bshk", xs, p["wk"].astype(x.dtype))
-        vx = jnp.einsum("bsd,dhk->bshk", xs, p["wv"].astype(x.dtype))
+        q = jnp.einsum(f"bsd,dhk->{lay}", x, p["wq"].astype(x.dtype))
+        kx = jnp.einsum(f"bsd,dhk->{lay}", xs, p["wk"].astype(x.dtype))
+        vx = jnp.einsum(f"bsd,dhk->{lay}", xs, p["wv"].astype(x.dtype))
         if kv_source is None:  # self-attention: rope at absolute positions
-            q = rope(q, positions, cfg.rope_theta)
-            kx = rope(kx, positions, cfg.rope_theta)
+            q = rope(q, positions, cfg.rope_theta, heads_first=heads_first)
+            kx = rope(kx, positions, cfg.rope_theta, heads_first=heads_first)
 
     new_cache = None
     with jax.named_scope("attn/core"):
@@ -257,21 +343,28 @@ def attention_block(
             v_cache = _scatter_cache(v_cache, vx, write_pos)
             new_cache = {"k": k_cache, "v": v_cache, "len": clen + S}
             q = pt.constrain(q, rules, ("batch", None, "act_heads", None))
+            _count("decode")
             out = decode_attention(q, k_cache, v_cache, valid_len)
+        elif heads_first:
+            _count("flash")
+            out = flash(q, kx, vx)
+            if collect_kv:  # the cache is (B, S, KV, D)
+                kx, vx = jnp.swapaxes(kx, 1, 2), jnp.swapaxes(vx, 1, 2)
         else:
             q = pt.constrain(q, rules, ("batch", "seq", "act_heads", None))
             kx = pt.constrain(kx, rules, ("batch", "kv_seq", None, None))
             vx = pt.constrain(vx, rules, ("batch", "kv_seq", None, None))
+            _count("chunked")
             out = chunked_attention(q, kx, vx,
                                     causal=causal and kv_source is None,
                                     window=window, score_dtype=cfg.score_dtype,
                                     q_chunk=cfg.attn_chunk,
                                     kv_chunk=cfg.attn_chunk)
-            if collect_kv:
-                new_cache = {"k": kx.astype(jnp.bfloat16),
-                             "v": vx.astype(jnp.bfloat16)}
+        if collect_kv and cache is None:
+            new_cache = {"k": kx.astype(jnp.bfloat16),
+                         "v": vx.astype(jnp.bfloat16)}
     with jax.named_scope("attn/out"):
-        out = jnp.einsum("bshk,hkd->bsd", out.astype(x.dtype),
+        out = jnp.einsum(f"{lay},hkd->bsd", out.astype(x.dtype),
                          p["wo"].astype(x.dtype))
         return pt.constrain(out, rules, ("batch", "seq", None)), new_cache
 

@@ -145,3 +145,188 @@ def test_flash_attention_property(sq, sk, h, rep):
     o1 = ops.flash_attention(q, k, v, causal=True, bq=32, bk=32)
     o2 = ref.attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(o1, o2, rtol=5e-4, atol=5e-5)
+
+
+@pytest.mark.parametrize("H,KV", [(9, 3), (4, 1)])
+@pytest.mark.parametrize("S", [256, 512])
+def test_flash_attention_grads_match_reference(H, KV, S):
+    """Forward and dq/dk/dv of the kernel's own backward against
+    ``jax.grad`` of the oracle in f32. Blocks of 128 over S 256 and 512
+    hold blocks skipped above the diagonal and masked ones on it."""
+    D = 64
+    ks = jax.random.split(jax.random.PRNGKey(S + H), 4)
+    q = jax.random.normal(ks[0], (1, H, S, D), jnp.float32)
+    k = jax.random.normal(ks[1], (1, KV, S, D), jnp.float32)
+    v = jax.random.normal(ks[2], (1, KV, S, D), jnp.float32)
+    do = jax.random.normal(ks[3], (1, H, S, D), jnp.float32)
+
+    def flash(q, k, v):
+        return ops.flash_attention(q, k, v, causal=True, bq=128, bk=128)
+
+    def oracle(q, k, v):
+        return ref.attention_ref(q, k, v, causal=True)
+
+    np.testing.assert_allclose(flash(q, k, v), oracle(q, k, v),
+                               rtol=1e-4, atol=1e-5)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * do), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(oracle(*a) * do), (0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4, err_msg=name)
+
+
+def test_flash_attention_grads_over_several_head_blocks_per_kv_head(
+        monkeypatch):
+    """Where VMEM holds fewer than all of a kv head's query heads, each
+    grid step takes a block of them and dK/dV accumulate over the blocks:
+    the gradients still match the oracle."""
+    from repro.kernels import flash_attention as fa
+
+    H, KV, S, D = 8, 2, 256, 64
+    monkeypatch.setattr(fa, "VMEM_HEAD_ROWS", 2 * 128 * D)
+    fwd, bwd = fa.plans((1, H, S, D), (1, KV, S, D), causal=True,
+                        interpret=True, bq=128, bk=128)
+    assert (fwd.group, fwd.n_sub, bwd.group, bwd.n_sub) == (2, 2, 2, 2)
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    q = jax.random.normal(ks[0], (1, H, S, D), jnp.float32)
+    k = jax.random.normal(ks[1], (1, KV, S, D), jnp.float32)
+    v = jax.random.normal(ks[2], (1, KV, S, D), jnp.float32)
+    do = jax.random.normal(ks[3], (1, H, S, D), jnp.float32)
+
+    def flash(q, k, v):
+        return ops.flash_attention(q, k, v, causal=True, bq=128, bk=128)
+
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * do), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(ref.attention_ref(*a) * do),
+                    (0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4, err_msg=name)
+
+
+# ---------------------------------------------------------------------------
+# which attention path the model takes
+# ---------------------------------------------------------------------------
+
+
+def _routing_case(name):
+    """(function, args) of one attention call of the smoke smollm: S 128,
+    3 query heads over 1 kv head of 16."""
+    from repro import configs
+    from repro.core import partition as pt
+    from repro.models import common as cm
+
+    cfg = configs.smoke("smollm-135m")
+    p = pt.init_tree(jax.random.PRNGKey(0), cm.attn_defs(cfg))
+    rules = pt.AxisRules(table=())
+    S = 96 if name == "ragged" else 128
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, S, cfg.d_model),
+                          jnp.bfloat16)
+    pos = jnp.broadcast_to(jnp.arange(S)[None], (2, S))
+    kw = {}
+    if name == "window":
+        kw["window"] = 32
+    elif name == "cross":
+        kw["kv_source"] = x[:, ::-1]
+    elif name == "decode":
+        D, KV = cfg.resolved_head_dim, cfg.n_kv_heads
+        kw["cache"] = {"k": jnp.zeros((2, S, KV, D), jnp.bfloat16),
+                       "v": jnp.zeros((2, S, KV, D), jnp.bfloat16),
+                       "len": jnp.int32(5)}
+        x, pos = x[:, :1], pos[:, :1]
+    elif name == "seq_sharded":
+        rules = pt.AxisRules(table=(("seq", ("model",)),))
+    return (lambda x: cm.attention_block(p, x, pos, cfg, rules, causal=True,
+                                         **kw)[0]), (x,)
+
+
+@pytest.mark.parametrize("name,path", [
+    ("causal_self", "flash"), ("window", "chunked"), ("cross", "chunked"),
+    ("decode", "decode"), ("seq_sharded", "chunked"), ("ragged", "chunked"),
+])
+def test_attention_takes_the_flash_kernel_only_where_it_is_exact(
+        monkeypatch, name, path):
+    """With the program traced for a TPU, only causal self-attention with
+    no window or softcap, over whole blocks of an unsharded sequence, takes
+    the kernel; the per-trace tally counts the path each call took."""
+    from repro.models import common as cm
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    fn, args = _routing_case(name)
+    with cm.attention_paths() as tally:
+        jax.eval_shape(fn, *args)
+    assert dict(tally) == {path: 1}
+
+
+def test_softcapped_attention_is_not_the_flash_kernels(monkeypatch):
+    """``chunked_attention``'s softcap is maths the kernel does not do."""
+    from repro.core import partition as pt
+    from repro.models import common as cm
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    rules = pt.AxisRules(table=())
+    shapes = (2, 128, 3, 16), (2, 128, 1, 16)
+    assert cm.flash_path(*shapes, rules, causal=True, window=0) is not None
+    assert cm.flash_path(*shapes, rules, causal=True, window=0,
+                         softcap=30.0) is None
+
+
+def test_attention_stays_on_chunked_attention_off_the_tpu():
+    from repro.models import common as cm
+
+    fn, args = _routing_case("causal_self")
+    with cm.attention_paths() as tally:
+        jax.eval_shape(fn, *args)
+    assert dict(tally) == {"chunked": 1}
+
+
+def test_train_step_through_the_flash_kernel_matches_chunked_attention(
+        monkeypatch):
+    """The smoke smollm's loss and gradients through the kernel (interpret
+    mode) equal those through ``chunked_attention``; the traced step's
+    tally holds only flash calls."""
+    from repro import configs
+    from repro.config import ShapeConfig
+    from repro.models import common as cm
+    from repro.models import registry
+
+    cfg = configs.smoke("smollm-135m")
+    b = registry.build(cfg)
+    params = b.init(jax.random.PRNGKey(0))
+    specs = b.input_specs(ShapeConfig("t", 128, 2, "train"))
+    toks = jax.random.randint(jax.random.PRNGKey(1), specs["tokens"].shape,
+                              0, cfg.vocab_size)
+    batch = {"tokens": toks, "labels": toks}
+    loss0, g0 = jax.jit(jax.value_and_grad(b.loss))(params, batch)
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    with cm.attention_paths() as tally:  # a new function: traced anew
+        loss1, g1 = jax.jit(jax.value_and_grad(b.loss))(params, batch)
+    assert set(tally) == {"flash"}
+    np.testing.assert_allclose(float(loss1), float(loss0), rtol=1e-3)
+    for a, c in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
+        a, c = np.asarray(a, np.float32), np.asarray(c, np.float32)
+        np.testing.assert_allclose(a, c, rtol=0.02,
+                                   atol=0.02 * float(np.max(np.abs(c))))
+
+
+def test_prefill_through_the_flash_kernel_matches_chunked_attention(
+        monkeypatch):
+    """Prefill through the kernel returns the last logits and a (B, S, KV,
+    D) cache equal to those through ``chunked_attention``."""
+    from repro import configs
+    from repro.config import ShapeConfig
+    from repro.models import registry
+
+    cfg = configs.smoke("smollm-135m")
+    b = registry.build(cfg)
+    params = b.init(jax.random.PRNGKey(0))
+    specs = b.input_specs(ShapeConfig("p", 128, 2, "prefill"))
+    batch = {"tokens": jax.random.randint(
+        jax.random.PRNGKey(2), specs["tokens"].shape, 0, cfg.vocab_size)}
+    want = jax.jit(lambda p, x: b.prefill(p, x))(params, batch)
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    got = jax.jit(lambda p, x: b.prefill(p, x))(params, batch)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, c in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.shape == c.shape
+        a, c = np.asarray(a, np.float32), np.asarray(c, np.float32)
+        np.testing.assert_allclose(a, c, rtol=0.02,
+                                   atol=0.02 * float(np.max(np.abs(c)) or 1))

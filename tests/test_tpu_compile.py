@@ -1,8 +1,8 @@
 """Compile-only checks for one TPU v5e chip, made without the chip: the
 installed TPU compiler compiles for a described v5e topology and nothing
 runs. They catch what CPU interpret mode cannot: block shapes the Mosaic
-lowering refuses, and a train step whose host-tier layouts the compiler
-rejects.
+lowering refuses, more VMEM than a kernel may use, and a train step whose
+host-tier layouts the compiler rejects.
 
 The topology is described inside a fixture, never at import: only one
 process may hold the TPU library, and every test worker imports this file.
@@ -13,12 +13,16 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import configs
-from repro.config import RunConfig, ShapeConfig, make_offload
+from repro.config import ParallelConfig, RunConfig, ShapeConfig, make_offload
+from repro.core import partition as pt
 from repro.core.engine import ZeroInfinityEngine
 from repro.kernels import fused_adam, flash_attention, tiled_matmul
+from repro.models import common as cm
 
 CFG = configs.get("smollm-135m")  # d_model 576, d_ff 1536, GQA 9/3, hd 64
 SEQ, BATCH = 2048, 8
+PARENT_STEP_TEMP = 6_347_916_288  # the all-HBM step's scratch before the
+# flash kernel took its attention (chunked_attention's stacked residuals)
 
 
 @pytest.fixture(scope="module")
@@ -58,6 +62,12 @@ def _kernel_cases(chip):
     rows = d * ff // fused_adam.LANE  # one (d_model, d_ff) leaf, flattened
     f32 = [_spec((rows, fused_adam.LANE), jnp.float32, chip)] * 4
     tokens = BATCH * SEQ // 4
+    H, KV = CFG.n_heads, CFG.n_kv_heads
+    q = _spec((1, H, SEQ, hd), jnp.bfloat16, chip)
+    kv = _spec((1, KV, SEQ, hd), jnp.bfloat16, chip)
+    rows = _spec((1, H, 1, SEQ), jnp.float32, chip)  # log-sum-exp, D_i
+    _, bwd = flash_attention.plans(q.shape, kv.shape, causal=True,
+                                   interpret=False)
     return {
         "fused_adam": (
             lambda *a: fused_adam.fused_adam_flat(*a, interpret=False),
@@ -75,14 +85,19 @@ def _kernel_cases(chip):
         "flash_attention": (
             lambda q, k, v: flash_attention.flash_attention(
                 q, k, v, causal=True, interpret=False),
-            [_spec((1, CFG.n_heads, SEQ, hd), jnp.bfloat16, chip),
-             _spec((1, CFG.n_kv_heads, SEQ, hd), jnp.bfloat16, chip),
-             _spec((1, CFG.n_kv_heads, SEQ, hd), jnp.bfloat16, chip)]),
+            [q, kv, kv]),
+        "flash_attention_dq": (
+            lambda *a: flash_attention.backward_dq(*a, bwd),
+            [q, kv, kv, q, rows, rows]),
+        "flash_attention_dkv": (
+            lambda *a: flash_attention.backward_dkv(*a, bwd),
+            [q, kv, kv, q, rows, rows]),
     }
 
 
 @pytest.mark.parametrize("name", ["fused_adam", "tiled_matmul",
-                                  "quantized_matmul", "flash_attention"])
+                                  "quantized_matmul", "flash_attention",
+                                  "flash_attention_dq", "flash_attention_dkv"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, args = _kernel_cases(one_chip)[name]
     compiled = jax.jit(fn).lower(*args).compile()
@@ -132,3 +147,79 @@ def test_every_op_of_the_v5e_step_is_in_a_model_region(host_step):
                    if "S(5)" in text.split(f"%{t} = ", 1)[1].split(" ", 1)[0]]
     assert host_copies
     assert {rmap[t][0] for t in host_copies} == {"offload"}
+
+
+def _mesh(devices, shape):
+    return jax.make_mesh(shape, ("data", "model"), devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+
+
+@pytest.fixture(scope="module")
+def hbm_step(topo):
+    """The benchmark's all-HBM step (every state in HBM, batch 8 x 2048,
+    undonated), compiled for one described v5e chip, with the tally of the
+    attention paths its trace took: (compiled, tally)."""
+    run = RunConfig(model=CFG, offload=make_offload(opt_tier="device"))
+    eng = ZeroInfinityEngine(run, _mesh(topo.devices[:1], (1, 1)))
+    with cm.attention_paths() as tally:
+        lowered = eng.lower_train(ShapeConfig("t", SEQ, BATCH, "train"),
+                                  donate=False)
+    return lowered.compile(), dict(tally)
+
+
+def _kernels(text):
+    """The step's top-level Pallas kernel calls."""
+    from perfbench import regions
+
+    return [t for t in regions.top_level_ops(text, ("custom-call",))
+            if 'custom_call_target="tpu_custom_call"'
+            in text.split(f"%{t} = ", 1)[1].split("\n", 1)[0]]
+
+
+def test_all_hbm_step_runs_attention_through_the_flash_kernel_on_v5e(
+        hbm_step):
+    """Compiled for the chip, the all-HBM step takes the flash kernel: its
+    forward (once more in the rematerialised forward) and both backward
+    kernels are the step's only Pallas calls, all in the ``attn/core``
+    region; every fusion, dot and convolution keeps a model region; and the
+    step's scratch is no more than it was with ``chunked_attention``."""
+    from perfbench import regions
+
+    compiled, tally = hbm_step
+    assert tally == {"flash": 1}  # the layer scan traces its body once
+    text = compiled.as_text()
+    rmap = regions.region_map(text)
+    tops = regions.top_level_ops(text)
+    assert [t for t in tops if rmap[t][0] == regions.OTHER] == []
+    kernels = _kernels(text)
+    assert {rmap[t] for t in kernels} == {
+        ("attn/core", "fwd"), ("attn/core", "remat"), ("attn/core", "bwd")}
+    assert sorted(t.rsplit(".", 1)[0] for t in kernels) == [
+        "flash_attention_dkv", "flash_attention_dq", "flash_attention_fwd",
+        "flash_attention_fwd"]
+    assert compiled.memory_analysis().temp_size_in_bytes <= PARENT_STEP_TEMP
+
+
+def test_flash_attention_runs_per_device_over_four_chips(topo):
+    """On a mesh of four described chips with the batch over ``data``, the
+    attention block's kernel runs under ``shard_map``: each chip computes
+    its own rows, and no collective gathers the kernel's operands."""
+    mesh = _mesh(topo.devices, (4, 1))
+    rules = pt.make_rules(CFG, mesh, ParallelConfig(), for_state="act")
+    rows = jax.sharding.NamedSharding(mesh, jax.P("data"))
+    every = jax.sharding.NamedSharding(mesh, jax.P())
+    p = {name: _spec(d.shape, jnp.bfloat16, every)
+         for name, d in cm.attn_defs(CFG).items()}
+    x = _spec((BATCH, SEQ, CFG.d_model), jnp.bfloat16, rows)
+    pos = _spec((BATCH, SEQ), jnp.int32, rows)
+
+    def loss(p, x, pos):
+        out, _ = cm.attention_block(p, x, pos, CFG, rules)
+        return jnp.sum(out.astype(jnp.float32))
+
+    with jax.set_mesh(mesh), cm.attention_paths() as tally:
+        lowered = jax.jit(jax.grad(loss, (0, 1))).lower(p, x, pos)
+    text = lowered.compile().as_text()
+    assert tally == {"flash": 1}
+    assert len(_kernels(text)) == 3  # forward, dq, dk/dv
+    assert "all-gather" not in text and "all-to-all" not in text
