@@ -10,8 +10,8 @@ float32 reference computes from the same weights and rows:
 - ``update_gap``: the same for the change of the parameters after the
   checked steps, over the leaves the reference's gradient moves.
 
-A leaf is one layer's slice of a stacked weight, or the embedding, or the
-final norm.
+A leaf is one layer's slice of a weight stacked by layer, or a weight that
+is not (the reference's ``leaf_norms`` gives a list or one number).
 """
 from __future__ import annotations
 

@@ -4,8 +4,24 @@ The cell's files are found by the names in ``BENCHMARK.json``:
 ``perfbench/configs/<config>.json`` (the model as it is run),
 ``perfbench/traffic/<traffic>.json`` (engine, tiers, mesh, batch, optimizer),
 ``perfbench/limits/<workload>.json`` (what ``correct`` allows) and
-``perfbench/metrics/<metric>.py`` (one reader per metric). A reference model
-is named by the configuration and lives in ``perfbench/references/``.
+``perfbench/metrics/<metric>.py`` (one reader per metric). The reference
+model is ``perfbench/references/<reference>.py``, named by the
+configuration.
+
+Nothing here knows an architecture. Of a configuration the harness reads
+``name``, ``reference``, ``vocab_size`` (the token ids), ``reduced`` and the
+``program`` block: the program's ModelConfig (``program_model``) and where
+each of the reference's weights sits in the program's parameter tree
+(``leaf_map``); every other key is the reference's. A reference module
+exports ``init_weights(cfg, key)`` (the canonical weights, by leaf name),
+``Reference(cfg, devices, mode=...)`` whose ``train(weights, batches, hp)``
+returns each step's loss and each leaf's norms, and the counts
+``matmul_params_per_token(cfg)`` and ``attention_flops_per_token(cfg,
+seq_len)``. A metric reader's ``read(ctx)`` gets the window (``setup_s``,
+``window_s``, ``steps``, ``tokens``, ``chips``, ``seq_len``, ``peak_bytes``),
+the cell's ``config`` and ``reference`` module, ``model_flops_per_token``,
+and in a traced run ``trace`` (``trace_reduce.Summary``), ``peak_flops`` and
+``step_text``, the compiled step's HLO text (else ``None``).
 
 The window drives the system's own training step: ``InfinityExecutor`` built
 from the cell's files, its ``make_train_step()`` in a closed loop, one loss
@@ -22,6 +38,7 @@ import functools
 import gc
 import importlib.util
 import json
+import math
 import shutil
 import sys
 import time
@@ -104,7 +121,10 @@ def seed_key(seed: int):
 
 
 def enable_cache(root: Path) -> None:
-    """The program's persistent compile cache, every program kept in it."""
+    """The program's persistent compile cache, every program kept in it.
+    Keyed with the programs' metadata, so that the step's text that the
+    readers map to regions carries this checkout's scope names even where
+    a cache holds the same program compiled from another checkout."""
     import jax
 
     sys.path.insert(0, str(root / "src"))
@@ -113,6 +133,7 @@ def enable_cache(root: Path) -> None:
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 # ---------------------------------------------------------------------------
@@ -121,60 +142,125 @@ def enable_cache(root: Path) -> None:
 
 
 def program_model(cfg: dict):
-    """The program's ModelConfig for the configuration file, or an error
-    for a setting the program cannot run."""
+    """The program's ModelConfig from the configuration's ``program`` block:
+    the registered ``arch`` with its ``fields`` replaced. An unknown field is
+    refused, and so is a ``fixed`` value (one the program cannot change) that
+    the configuration runs otherwise, or that its source states otherwise
+    without the key in ``reduced``."""
     from repro import configs
 
-    fixed = {"hidden_act": "silu", "rms_norm_eps": 1e-6,
-             "embedding_multiplier": 1.0, "residual_multiplier": 1.0,
-             "logits_scaling": 1.0,
-             "attention_multiplier": cfg["head_dim"] ** -0.5}
-    if cfg.get("num_local_experts"):
-        fixed["router_group"] = 1024
-    for key, value in fixed.items():
+    block, name = cfg["program"], cfg["name"]
+    base = configs.get(block["arch"])
+    unknown = sorted(set(block["fields"]) - {
+        f.name for f in dataclasses.fields(base)})
+    if unknown:
+        raise SystemExit(f"{name}: the program's ModelConfig has no field "
+                         f"{', '.join(unknown)}")
+    for key, value in block.get("fixed", {}).items():
         if cfg.get(key, value) != value:
-            raise SystemExit(f"{cfg['name']}: the program runs {key}={value}, "
+            raise SystemExit(f"{name}: the program runs {key}={value}, "
                              f"the configuration asks {cfg[key]}")
-    E = cfg.get("num_local_experts", 0)
-    return dataclasses.replace(
-        configs.get(cfg["arch"]), n_layers=cfg["num_hidden_layers"],
-        d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        rope_theta=float(cfg["rope_theta"]),
-        tie_embeddings=cfg["tie_word_embeddings"], n_experts=E,
-        top_k=cfg.get("num_experts_per_tok", 0),
-        capacity_factor=cfg.get("capacity_factor", 1.25))
+        source = cfg.get("published", {}).get(key, value)
+        if source != value and key not in cfg.get("reduced", []):
+            raise SystemExit(f"{name}: the program runs {key}={value}, the "
+                             f"source states {source}: list {key} in "
+                             f"'reduced'")
+    return dataclasses.replace(base, **block["fields"])
 
 
-def program_params(w: dict, cfg: dict, padded_vocab: int) -> dict:
-    """Canonical weights -> the program's parameter tree (traceable)."""
-    import jax.numpy as jnp
+@dataclasses.dataclass(frozen=True)
+class Leaf:
+    """One canonical leaf of the reference and where the program keeps it."""
 
-    tok = jnp.pad(w["embed"], ((0, padded_vocab - cfg["vocab_size"]), (0, 0)))
-    blocks = {"ln1": {"scale": w["ln1"]}, "ln2": {"scale": w["ln2"]},
-              "attn": {k: w[k] for k in ("wq", "wk", "wv", "wo")}}
-    mlp = {"w_in": w["w_up"], "w_gate": w["w_gate"], "w_out": w["w_down"]}
-    if cfg.get("num_local_experts"):
-        blocks["moe"] = dict(mlp, router=w["router"])
-    else:
-        blocks["mlp"] = mlp
-    return {"embed": {"tok": tok}, "blocks": blocks,
-            "ln_f": {"scale": w["ln_f"]}}
+    name: str
+    path: tuple  # keys into the program's parameter tree
+    stacked: bool  # by layer on its leading axis: one norm per layer
+    shape: tuple  # the reference's
+    program_shape: tuple  # the program's: the same size, or zero-padded
+
+    def place(self, tree: dict, x) -> None:
+        """Put canonical ``x`` into the program's ``tree`` (traceable)."""
+        import jax.numpy as jnp
+
+        if math.prod(self.shape) == math.prod(self.program_shape):
+            x = x.reshape(self.program_shape)
+        else:
+            x = jnp.pad(x, [(0, b - a) for a, b in
+                            zip(self.shape, self.program_shape)])
+        for key in self.path[:-1]:
+            tree = tree.setdefault(key, {})
+        tree[self.path[-1]] = x
+
+    def find(self, tree: dict):
+        """The program's leaf in a program-shaped ``tree``."""
+        for key in self.path:
+            tree = tree[key]
+        return tree
+
+    def canonical(self, x):
+        """The program's leaf ``x`` as the reference holds it: the inverse
+        of ``place``."""
+        if self.shape == self.program_shape:
+            return x
+        if math.prod(self.shape) == math.prod(self.program_shape):
+            return x.reshape(self.shape)
+        return x[tuple(slice(0, a) for a in self.shape)]
 
 
-def canonical_leaves(tree: dict) -> dict:
-    """The program's parameter tree (or a tree shaped like it) by canonical
-    leaf name; the inverse of ``program_params``' renaming."""
-    b = tree["blocks"]
-    mlp = b.get("moe", b.get("mlp"))
-    out = {"embed": tree["embed"]["tok"], "ln_f": tree["ln_f"]["scale"],
-           "ln1": b["ln1"]["scale"], "ln2": b["ln2"]["scale"],
-           "w_up": mlp["w_in"], "w_gate": mlp["w_gate"],
-           "w_down": mlp["w_out"], **b["attn"]}
-    if "router" in mlp:
-        out["router"] = mlp["router"]
+def leaf_map(cfg: dict, canonical: dict, defs) -> list:
+    """The configuration's ``program.leaves`` checked against the
+    reference's weights (``canonical``: name -> shape and dtype) and the
+    program's parameter definitions ``defs``. Each entry is ``{"path":
+    "a/b/c", "stacked": bool, "pad": [axis, ...]}``: the program's leaf holds
+    the canonical one reshaped where their sizes match, or zero-padded along
+    the ``pad`` axes, where the program's is larger (a padded vocabulary)."""
+    import jax
+
+    from repro.core.partition import ParamDef
+
+    name = cfg["name"]
+    flat, _ = jax.tree_util.tree_flatten_with_path(
+        defs, is_leaf=lambda x: isinstance(x, ParamDef))
+    program = {"/".join(k.key for k in path): d for path, d in flat}
+    entries = cfg["program"]["leaves"]
+    filled = [e["path"] for e in entries.values()]
+    for what, names in (
+            ("the reference's leaves with no path in program.leaves",
+             set(canonical) - set(entries)),
+            ("program.leaves names leaves the reference does not have",
+             set(entries) - set(canonical)),
+            ("no leaf of the reference fills the program's",
+             set(program) - set(filled))):
+        if names:
+            raise SystemExit(f"{name}: {what}: {', '.join(sorted(names))}")
+    out = []
+    for leaf, e in sorted(entries.items()):
+        if e["path"] not in program or filled.count(e["path"]) > 1:
+            raise SystemExit(f"{name}: {leaf!r} maps to {e['path']!r}, which "
+                             f"is not one program leaf of its own")
+        d, ref = program[e["path"]], canonical[leaf]
+        shape, pshape = tuple(ref.shape), tuple(d.shape)
+        larger = [i for i, (a, b) in enumerate(zip(shape, pshape)) if a != b]
+        fits = math.prod(shape) == math.prod(pshape) or (
+            len(shape) == len(pshape) and set(larger) <= set(e.get("pad", []))
+            and all(shape[i] < pshape[i] for i in larger))
+        if not fits:
+            raise SystemExit(f"{name}: {leaf!r} {shape} does not fit the "
+                             f"program's {e['path']!r} {pshape}")
+        if str(ref.dtype) != d.dtype:
+            raise SystemExit(f"{name}: {leaf!r} is {ref.dtype}, the program "
+                             f"keeps {e['path']!r} in {d.dtype}")
+        out.append(Leaf(leaf, tuple(e["path"].split("/")),
+                        e.get("stacked", False), shape, pshape))
     return out
+
+
+def program_params(w: dict, leaves: list) -> dict:
+    """Canonical weights -> the program's parameter tree (traceable)."""
+    tree = {}
+    for leaf in leaves:
+        leaf.place(tree, w[leaf.name])
+    return tree
 
 
 class Program:
@@ -187,6 +273,7 @@ class Program:
         from repro.config import (RunConfig, ShapeConfig, TrainConfig,
                                   make_offload, make_parallel)
         from repro.core.executor import InfinityExecutor
+        from repro.models import registry
         from repro.optim import adam
 
         t, cfg = cell.traffic, cell.config
@@ -194,6 +281,10 @@ class Program:
             raise SystemExit(f"traffic {cell.workload['traffic']}: the "
                              f"harness builds pjit-engine state only")
         self.model = program_model(cfg)
+        self.leaves = leaf_map(
+            cfg, jax.eval_shape(functools.partial(reference.init_weights, cfg),
+                                jax.random.PRNGKey(0)),
+            registry.build(self.model).defs)
         opt = t["optimizer"]
         self.hp = opt
         run = RunConfig(
@@ -214,17 +305,17 @@ class Program:
         self.shape = ShapeConfig("bench", t["seq_len"], t["global_batch"],
                                  "train")
         shardings = self.executor.state_shardings()
-        pv = self.model.padded_vocab()
+
+        def params(key):
+            return program_params(reference.init_weights(cfg, key),
+                                  self.leaves)
 
         def init(key):
-            params = program_params(reference.init_weights(cfg, key), cfg, pv)
-            return {"params": params, "opt": adam.init_state(params)}
+            p = params(key)
+            return {"params": p, "opt": adam.init_state(p)}
 
         self.init = jax.jit(init, out_shardings=shardings)
-        self.params0 = jax.jit(
-            lambda key: program_params(reference.init_weights(cfg, key), cfg,
-                                       pv),
-            out_shardings=shardings["params"])
+        self.params0 = jax.jit(params, out_shardings=shardings["params"])
         B, S, V = t["global_batch"], t["seq_len"], cfg["vocab_size"]
         if t["tokens"] != "uniform":
             raise SystemExit(f"unknown token distribution {t['tokens']!r}")
@@ -240,12 +331,6 @@ class Program:
         self.step = self.jitted
         self.tokens_per_step = B * S
 
-    def step_temp_bytes(self, state, batch) -> int:
-        """Scratch of the compiled step that the window drives, per chip:
-        device memory that the allocator's statistics leave out."""
-        compiled = self.jitted.lower(state, batch).compile()
-        return int(compiled.memory_analysis().temp_size_in_bytes)
-
     def leaf_norms(self, tree, minus=None) -> dict:
         """Norms of each canonical leaf of a parameter-shaped ``tree`` (or of
         ``tree - minus``): one per layer for stacked leaves. One leaf at a
@@ -253,14 +338,18 @@ class Program:
         depend on how far the host runs ahead."""
         import jax
 
-        base = canonical_leaves(minus) if minus is not None else {}
-        out = {}
-        for name, x in canonical_leaves(tree).items():
+        def leaf_of(t, leaf):
+            x = leaf.find(t)
             if x.sharding.memory_kind not in (None, "device"):
                 x = jax.device_put(x, x.sharding.with_memory_kind("device"))
-            stacked = name not in ("embed", "ln_f")
-            out[name] = jax.device_get(_norm_fn(stacked)(x, base.get(name)))
-            del x
+            return leaf.canonical(x)
+
+        out = {}
+        for leaf in self.leaves:
+            x = leaf_of(tree, leaf)
+            base = None if minus is None else leaf_of(minus, leaf)
+            out[leaf.name] = jax.device_get(_norm_fn(leaf.stacked)(x, base))
+            del x, base
         return {k: v.tolist() for k, v in out.items()}
 
 
@@ -302,6 +391,15 @@ class CompileCounter:
         self.n = 0
 
 
+def model_flops_per_token(ref, cfg: dict, seq_len: int) -> float:
+    """Model FLOPs of one token of a training step, from the counts of the
+    configuration's reference ``ref``: forward and backward are three times
+    the forward's multiply-adds, two FLOPs each, over the weights a token
+    multiplies with, plus causal attention."""
+    return 6 * ref.matmul_params_per_token(cfg) + \
+        ref.attention_flops_per_token(cfg, seq_len)
+
+
 def reference_module(cell: Cell):
     return load_module(cell.root / "perfbench" / "references" /
                        f"{cell.config['reference']}.py")
@@ -326,8 +424,8 @@ def checked_steps(prog: Program, key):
                 k: ([x / (1 - b1) for x in v] if isinstance(v, list)
                     else v / (1 - b1)) for k, v in m1.items()}
         for k, v in metrics.items():
-            if k.startswith("moe_dropped"):
-                extra.setdefault(k, []).append(float(v))
+            if k != "loss":
+                extra.setdefault(k, []).append(jax.device_get(v).tolist())
     readings["update_norms"] = prog.leaf_norms(
         state["opt"].master, minus=prog.params0(key))
     jax.block_until_ready(state)
@@ -388,7 +486,7 @@ def run(root: Path, name: str, seed: int, seconds: float, trace: bool, *,
     import jax
     import numpy as np
 
-    from perfbench import compare, flops, trace_reduce
+    from perfbench import compare, trace_reduce
 
     cell = load_cell(root, name)
     if devices is None:
@@ -399,7 +497,13 @@ def run(root: Path, name: str, seed: int, seconds: float, trace: bool, *,
     prog = Program(cell, devices, ref_mod)
     key = seed_key(seed)
     state, readings, extra = checked_steps(prog, key)
-    temp = prog.step_temp_bytes(state, prog.feed(key, CHECKED_STEPS + 1))
+    # the step the window drives, from the cache: its scratch, which the
+    # allocator's statistics leave out, and for the readers its text
+    compiled = prog.jitted.lower(
+        state, prog.feed(key, CHECKED_STEPS + 1)).compile()
+    temp = int(compiled.memory_analysis().temp_size_in_bytes)
+    step_text = compiled.as_text() if trace else None
+    del compiled
     setup_s = time.perf_counter() - t_start
     trace_dir = root / ".perfbench" / "trace" / name
     if trace:
@@ -429,8 +533,9 @@ def run(root: Path, name: str, seed: int, seconds: float, trace: bool, *,
     ctx = {"setup_s": setup_s, "window_s": window_s, "steps": steps,
            "tokens": steps * tokens_per_step, "peak_bytes": peak,
            "chips": len(devices), "seq_len": cell.traffic["seq_len"],
-           "model_flops_per_token": flops.model_flops_per_token(
-               cfg, cell.traffic["seq_len"]),
+           "config": cfg, "reference": ref_mod, "step_text": step_text,
+           "model_flops_per_token": model_flops_per_token(
+               ref_mod, cfg, cell.traffic["seq_len"]),
            "trace": None}
     result_device = {"platform": chip.platform, "kind": chip.device_kind,
                      "count": len(devices), "memory_peak_bytes": peak}

@@ -1,6 +1,6 @@
 """Model FLOPs of the steps in the traced window (forward and backward,
-causal attention, no recompute; perfbench/flops.py) over the window times the
-chips times the peak FLOP/s."""
+causal attention, no recompute; as the cell's reference counts them) over
+the window times the chips times the peak FLOP/s."""
 
 
 def read(ctx):

@@ -29,15 +29,15 @@ from pathlib import Path  # noqa: E402
 ROOT = Path(__file__).resolve().parents[1]
 
 
-def report(cell, rmap: dict, trace_dir: Path, steps: int, tokens: int,
-           peak_flops: float, hlo_lines: dict) -> dict:
-    from perfbench import flops, regions, trace_reduce
+def report(cell, ref_mod, rmap: dict, trace_dir: Path, steps: int,
+           tokens: int, peak_flops: float, hlo_lines: dict) -> dict:
+    from perfbench import regions, trace_reduce
 
     summary = trace_reduce.reduce_dir(trace_dir, cell.workload["chips"])
     path = sorted(trace_dir.rglob("*.xplane.pb"))[-1]
     st = regions.reduce_file(path, rmap, cell.workload["chips"])
     busy = st.step_busy_s()
-    attn = flops.attention_flops_per_token(
+    attn = ref_mod.attention_flops_per_token(
         cell.config, cell.traffic["seq_len"]) * tokens
     top = sorted(((e - s, name, r, d) for s, e, name, r, d in st.ops[0]),
                  reverse=True)
@@ -84,7 +84,8 @@ def main(argv=None) -> int:
     from repro.runtime import trace
 
     counter = harness.CompileCounter()
-    prog = harness.Program(cell, devices, harness.reference_module(cell))
+    ref_mod = harness.reference_module(cell)
+    prog = harness.Program(cell, devices, ref_mod)
     key = harness.seed_key(args.seed)
     state, _, _ = harness.checked_steps(prog, key)
     text = prog.jitted.lower(
@@ -118,8 +119,8 @@ def main(argv=None) -> int:
         line = {"window": label, "tracer": tracer, "bad": bad,
                 "compiles_in_window": counter.n,
                 "tokens_per_s_host": tokens / window_s}
-        line.update(report(cell, rmap, trace_dir, len(ends), tokens, peak,
-                           hlo_lines))
+        line.update(report(cell, ref_mod, rmap, trace_dir, len(ends), tokens,
+                           peak, hlo_lines))
         print(json.dumps(line), flush=True)
         if label == "two_steps":
             path = sorted(trace_dir.rglob("*.xplane.pb"))[-1]

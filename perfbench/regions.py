@@ -289,47 +289,3 @@ def reduce_file(path, rmap: dict, n_devices: int,
     host = [(*clip(s, e), n) for s, e, n in host
             if inside(s, e) and not n.startswith("$")]
     return StepTrace(ws, we, ops, busy, host, steps)
-
-
-# ---------------------------------------------------------------------------
-# the region map of a benchmark run's step
-# ---------------------------------------------------------------------------
-
-
-def compiled_step_text(root, workload: str) -> str:
-    """The HLO text of the cell's compiled train step, rebuilt from the
-    cell's files as the harness builds it, on the run's own chips.
-
-    The persistent compile cache keys a program without its metadata, so
-    the executable a run loaded may carry the names of another checkout
-    whose step differs only in its scopes. This compile keys the metadata
-    too: its names are this checkout's, and its instruction names those of
-    the executable that ran, since the optimized programs are the same."""
-    import jax
-
-    from perfbench import harness
-
-    cell = harness.load_cell(root, workload)
-    devices = jax.devices()[:cell.workload["chips"]]
-    prog = harness.Program(cell, devices, harness.reference_module(cell))
-    flag = "jax_compilation_cache_include_metadata_in_key"
-    keyed = getattr(jax.config, flag)
-    jax.config.update(flag, True)
-    try:
-        key = jax.random.PRNGKey(0)
-        state = jax.eval_shape(prog.init, key)
-        batch = jax.eval_shape(prog.feed, key, 0)
-        return prog.jitted.lower(state, batch).compile().as_text()
-    finally:
-        jax.config.update(flag, keyed)
-        prog.executor.close()
-
-
-def run_workload(argv) -> str | None:
-    """The ``--workload`` of a ``perfbench/run.py`` command line, if any."""
-    for i, arg in enumerate(argv):
-        if arg == "--workload" and i + 1 < len(argv):
-            return argv[i + 1]
-        if arg.startswith("--workload="):
-            return arg.split("=", 1)[1]
-    return None

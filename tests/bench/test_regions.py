@@ -7,10 +7,12 @@ import json
 import pytest
 import tiny
 
-from perfbench import flops, harness, regions
+from perfbench import harness, regions
 from perfbench import trace_reduce as tr
 
 ROOFLINE = tiny.REPO / "perfbench" / "metrics" / "attention_roofline.train.py"
+DECODER = harness.load_module(tiny.REPO / "perfbench/references/decoder.py")
+SMOLLM = harness.load_cell(tiny.REPO, "smollm-train-hbm").config
 
 
 @pytest.mark.parametrize("op_name,expected", [
@@ -72,8 +74,18 @@ def test_region_map_by_hand():
 def compiled_step(request, tmp_path_factory):
     """A tiny cell's train step as the harness builds it (remat on, as in
     the benchmark), compiled on the CPU: its HLO text."""
+    import jax
+
     root = tiny.make_root(tmp_path_factory.mktemp("regions"))
-    return request.param, regions.compiled_step_text(root, request.param)
+    cell = harness.load_cell(root, request.param)
+    prog = harness.Program(cell, jax.devices()[:1],
+                           harness.reference_module(cell))
+    key = jax.random.PRNGKey(0)
+    text = prog.jitted.lower(jax.eval_shape(prog.init, key),
+                             jax.eval_shape(prog.feed, key, 0)
+                             ).compile().as_text()
+    prog.executor.close()
+    return request.param, text
 
 
 def test_every_named_op_of_the_step_is_in_a_region(compiled_step):
@@ -113,19 +125,15 @@ def _summary(ops):
     (2e9, 100.0 * 2.5e12 / (2.0 * 197e12)),
     (0.0, None),
 ])
-def test_attention_roofline_reader(monkeypatch, core_ns, expected):
+def test_attention_roofline_reader(core_ns, expected):
     reader = harness.load_module(ROOFLINE)
-    monkeypatch.setattr(regions, "compiled_step_text",
-                        lambda root, workload: HLO)
-    monkeypatch.setattr(reader.sys, "argv",
-                        ["run.py", "--workload", "smollm-train-hbm"])
-    cfg = harness.load_cell(tiny.REPO, "smollm-train-hbm").config
-    per_token = flops.attention_flops_per_token(cfg, 2048)
+    per_token = DECODER.attention_flops_per_token(SMOLLM, 2048)
     ops = [(0, 5e8, "dot.2", "matmul")]
     if core_ns:
         ops.append((1e9, 1e9 + core_ns,
                     "bitcast_dynamic-update-slice_fusion.51", "compute"))
-    ctx = {"trace": _summary(ops), "seq_len": 2048,
+    ctx = {"trace": _summary(ops), "seq_len": 2048, "step_text": HLO,
+           "reference": DECODER, "config": SMOLLM,
            "tokens": 2.5e12 / per_token, "peak_flops": 197e12}
     value = reader.read(ctx)
     if expected is None:
@@ -134,20 +142,33 @@ def test_attention_roofline_reader(monkeypatch, core_ns, expected):
         assert value == pytest.approx(expected, rel=1e-12)
 
 
-def test_attention_roofline_reader_needs_a_trace_and_a_workload(monkeypatch):
+def test_attention_roofline_reader_needs_a_trace_and_a_workload():
+    """The workload's step comes as its compiled text, from the harness."""
     reader = harness.load_module(ROOFLINE)
-    monkeypatch.setattr(reader.sys, "argv", ["run.py"])
     ctx = {"trace": _summary([]), "seq_len": 2048, "tokens": 1,
-           "peak_flops": 197e12}
-    # a trace without the run's cell is a fault, not a missing metric
-    with pytest.raises(RuntimeError, match="--workload"):
+           "peak_flops": 197e12, "step_text": None, "reference": DECODER,
+           "config": SMOLLM}
+    # a trace without the run's step is a fault, not a missing metric
+    with pytest.raises(RuntimeError, match="compiled step's text"):
         reader.read(ctx)
     assert reader.read(dict(ctx, trace=None)) is None
-    monkeypatch.setattr(reader.sys, "argv",
-                        ["run.py", "--workload=smollm-train-hbm"])
-    assert reader.read(dict(ctx, trace=None)) is None
-    assert regions.run_workload(["--workload=x"]) == "x"
+    assert reader.read(dict(ctx, trace=None, step_text=HLO)) is None
 
+
+def test_attention_roofline_counts_by_the_cells_reference():
+    """Attention FLOPs come from the reference in ``ctx``, whatever model
+    it is."""
+    class Counts:
+        @staticmethod
+        def attention_flops_per_token(cfg, seq_len):
+            return cfg["per_key"] * seq_len
+
+    reader = harness.load_module(ROOFLINE)
+    ops = [(1e9, 3e9, "bitcast_dynamic-update-slice_fusion.51", "compute")]
+    ctx = {"trace": _summary(ops), "seq_len": 100, "step_text": HLO,
+           "reference": Counts, "config": {"per_key": 5e6},
+           "tokens": 1e6, "peak_flops": 1e15}
+    assert reader.read(ctx) == pytest.approx(100 * 5e14 / (2.0 * 1e15))
 
 
 # ---------------------------------------------------------------------------
@@ -233,16 +254,14 @@ def test_attention_roofline_reader_on_the_recorded_trace(monkeypatch,
                                                          recorded):
     rmap, t = recorded
     reader = harness.load_module(ROOFLINE)
-    monkeypatch.setattr(regions, "compiled_step_text", lambda r, w: "")
     monkeypatch.setattr(regions, "region_map", lambda text: rmap)
-    monkeypatch.setattr(reader.sys, "argv",
-                        ["run.py", "--workload", "smollm-train-hbm"])
     summary = tr.reduce_file(HBM_TRACE, 1)
     # no op of the feed's module shares a name with an attn/core op
     assert regions.summary_region_s(summary, rmap, "attn/core") == (
         t.region_s("attn/core"))
     ctx = {"trace": summary, "seq_len": 2048, "tokens": 2 * 8 * 2048,
-           "peak_flops": 197e12}
+           "peak_flops": 197e12, "step_text": "", "reference": DECODER,
+           "config": SMOLLM}
     # 2 x 8 x 2048 tokens x 212.4 MFLOP over 1.935940308 s x 197e12
     assert reader.read(ctx) == pytest.approx(
         100 * 32768 * 3 * 30 * 2 * 2 * 9 * 64 * 1024.5

@@ -11,17 +11,53 @@ from pathlib import Path
 
 REPO = Path(__file__).resolve().parents[2]
 
-DENSE = {"name": "tiny-dense", "source": "test", "arch": "smollm-135m",
-         "reference": "decoder", "hidden_size": 48, "intermediate_size": 128,
+
+def leaves(mlp: str, router: bool = False) -> dict:
+    """``program.leaves`` of ``decoder.py``'s weights in the program's tree:
+    the dense block's MLP under ``blocks/mlp``, an MoE block's under
+    ``blocks/moe`` with its router."""
+    out = {"embed": {"path": "embed/tok", "pad": [0]},
+           "ln_f": {"path": "ln_f/scale"}}
+    layer = {"ln1": "ln1/scale", "ln2": "ln2/scale", "wq": "attn/wq",
+             "wk": "attn/wk", "wv": "attn/wv", "wo": "attn/wo",
+             "w_gate": f"{mlp}/w_gate", "w_up": f"{mlp}/w_in",
+             "w_down": f"{mlp}/w_out"}
+    if router:
+        layer["router"] = f"{mlp}/router"
+    out.update({k: {"path": f"blocks/{v}", "stacked": True}
+                for k, v in layer.items()})
+    return out
+
+
+FIXED = {"hidden_act": "silu", "rms_norm_eps": 1e-6,
+         "embedding_multiplier": 1.0, "residual_multiplier": 1.0,
+         "logits_scaling": 1.0, "attention_multiplier": 0.25}
+DENSE = {"name": "tiny-dense", "source": "test", "reference": "decoder",
+         "hidden_size": 48, "intermediate_size": 128,
          "num_hidden_layers": 2, "num_attention_heads": 3,
          "num_key_value_heads": 1, "head_dim": 16, "vocab_size": 128,
          "hidden_act": "silu", "rope_theta": 10000.0, "rms_norm_eps": 1e-6,
          "tie_word_embeddings": True, "num_local_experts": 0,
-         "num_experts_per_tok": 0}
-MOE = dict(DENSE, name="tiny-moe", arch="granite-moe-1b-a400m",
-           hidden_size=64, intermediate_size=32, num_attention_heads=4,
-           num_key_value_heads=2, vocab_size=131, num_local_experts=8,
-           num_experts_per_tok=2, capacity_factor=1.5, router_group=1024)
+         "num_experts_per_tok": 0,
+         "program": {"arch": "smollm-135m",
+                     "fields": {"n_layers": 2, "d_model": 48, "n_heads": 3,
+                                "n_kv_heads": 1, "head_dim": 16, "d_ff": 128,
+                                "vocab_size": 128, "rope_theta": 10000.0,
+                                "tie_embeddings": True, "n_experts": 0,
+                                "top_k": 0, "capacity_factor": 1.25},
+                     "fixed": FIXED, "leaves": leaves("mlp")}}
+MOE = dict(DENSE, name="tiny-moe", hidden_size=64, intermediate_size=32,
+           num_attention_heads=4, num_key_value_heads=2, vocab_size=131,
+           num_local_experts=8, num_experts_per_tok=2, capacity_factor=1.5,
+           router_group=1024,
+           program={"arch": "granite-moe-1b-a400m",
+                    "fields": {"n_layers": 2, "d_model": 64, "n_heads": 4,
+                               "n_kv_heads": 2, "head_dim": 16, "d_ff": 32,
+                               "vocab_size": 131, "rope_theta": 10000.0,
+                               "tie_embeddings": True, "n_experts": 8,
+                               "top_k": 2, "capacity_factor": 1.5},
+                    "fixed": dict(FIXED, router_group=1024),
+                    "leaves": leaves("moe", router=True)})
 # No MoE cell has limits read on the chip yet. These keep the dense cell's
 # grad and update limits; the loss limit is wider, since the program's
 # router takes bf16 logits and the tiny MoE's loss gap on the CPU is 3e-5.
@@ -49,8 +85,10 @@ def make_root(tmp: Path, cells=None, limits=None) -> Path:
     cell is held to ``limits``, by default the dense benchmark cell's or,
     for MoE, ``MOE_LIMITS``."""
     (tmp / "perfbench").mkdir(parents=True)
-    for sub in ("metrics", "references"):
-        os.symlink(REPO / "perfbench" / sub, tmp / "perfbench" / sub)
+    for sub in ("metrics", "references"):  # new files here stay in ``tmp``
+        (tmp / "perfbench" / sub).mkdir()
+        for f in (REPO / "perfbench" / sub).glob("*.py"):
+            os.symlink(f, tmp / "perfbench" / sub / f.name)
     os.symlink(REPO / "src", tmp / "src")
     for sub in ("configs", "traffic", "limits"):
         (tmp / "perfbench" / sub).mkdir()
