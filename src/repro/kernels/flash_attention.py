@@ -5,20 +5,36 @@ Forward: online softmax over KV blocks with BlockSpec VMEM tiling, so the
 (m, l, acc) scratch). It saves only the output and the per-row log-sum-exp
 (f32, ``(B, H, 1, Sq)``, a row per head so that it is lane-dense in HBM).
 
-Backward (``jax.custom_vjp``): two kernels in the usual flash structure.
+Backward (``jax.custom_vjp``): one fused kernel, ``flash_attention_bwd``,
+where its dQ accumulator fits in VMEM (``fused_backward``); else the split
+pair of the usual flash structure.
 
-- dK/dV: the grid walks KV blocks and inner sequential axes walk the
-  blocks of query heads that share the KV head and the q blocks; the
-  q-side index maps hand each step its heads, so each KV head accumulates
-  over its ``H // KV`` query heads. It computes in the transposed frame
-  (``k q^T``), where the log-sum-exp and ``D_i`` rows broadcast down the
-  sublanes.
-- dQ: the grid walks q blocks; the inner sequential axis walks KV blocks.
+- Fused: the grid walks a kv head's KV blocks, and inner sequential axes
+  walk the blocks of query heads that share the KV head and the q blocks;
+  the q-side index maps hand each step its heads. It computes in the
+  transposed frame (``k q^T``), where the log-sum-exp and ``D_i`` rows
+  broadcast down the sublanes. Each visited block recomputes ``p`` and
+  ``dS`` once and runs five block matmuls: the scores, ``dP``, and dV, dK
+  and dQ. dK/dV accumulate in VMEM over the kv block's q blocks and heads;
+  dQ accumulates in a VMEM scratch of the kv head's ``H // KV`` query heads
+  over the whole padded sequence (f32, lanes padded to 128: 3 MiB at
+  GQA 9/3, S 2048, head dim 64), written once, as the input dtype, after
+  the kv head's last block; the kernel's scoped VMEM is raised by that
+  scratch and its output block's buffers (``dq_resident_bytes``). So the
+  KV-block axis is sequential; batch and kv heads stay parallel. No dQ
+  partials reach HBM.
+- Split, where that scratch and its output block outgrow
+  ``VMEM_DQ_BYTES`` (long sequences, wide heads, many heads per kv head):
+  a dK/dV kernel on the fused kernel's grid, and a dQ kernel whose grid
+  walks q blocks with an inner sequential axis over KV blocks. Both
+  recompute the scores, ``p`` and ``dS``: seven block matmuls to the fused
+  kernel's five.
 
-Both recompute ``s = q k^T * scale`` and ``p = exp(s - lse)`` in VMEM and use
-``D_i = rowsum(dO * O)``, computed once outside the kernels. Matmul operands
-stay in the input dtype (bf16 in the model) with f32 accumulation; the
-softmax statistics and dS stay f32 until the operand cast.
+Every backward kernel recomputes ``s = q k^T * scale`` and
+``p = exp(s - lse)`` in VMEM and uses ``D_i = rowsum(dO * O)``, computed
+once outside the kernels. Matmul operands stay in the input dtype (bf16 in
+the model) with f32 accumulation; the softmax statistics and dS stay f32
+until the operand cast.
 
 Causality: blocks wholly above the diagonal are skipped in compute
 (``pl.when``) and in DMA (each index map clamps to the last block its row
@@ -44,7 +60,15 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 LANES = 128
 VMEM_HEAD_ROWS = 1 << 18  # q-block elements of the heads one step takes
+VMEM_SCOPED = 16 << 20  # a v5e's default scoped VMEM, which the split
+# kernels fit; the fused backward is granted its resident dQ on top of it
+# (dq_resident_bytes), up to VMEM_DQ_BYTES, so its limit stays within half
+# of a v5e's 128 MiB of VMEM. The v5e compiler takes the fused kernel up to
+# GQA 28/2 at S 8192 and head dim 128 (168 MiB as counted here) and refuses
+# 32/2 (192 MiB) and 8/1 at S 32768 (384 MiB)
+VMEM_DQ_BYTES = 48 << 20
 _NT = (((1,), (1,)), ((), ()))  # a @ b^T
+_TN = (((0,), (0,)), ((), ()))  # a^T @ b
 
 
 def block_size(s: int, cap: int) -> int:
@@ -81,6 +105,19 @@ def heads_per_step(n_rep: int, bq: int, d: int) -> int:
                if n_rep % g == 0 and (g == 1 or g * bq * d <= VMEM_HEAD_ROWS))
 
 
+def dq_resident_bytes(n_rep: int, sq: int, d: int) -> int:
+    """VMEM the fused backward holds for dQ: the f32 accumulator of a kv
+    head's ``n_rep`` query heads over ``sq`` padded rows and the output
+    block's two buffers, counted at 4 B (lanes padded to 128)."""
+    return 3 * n_rep * sq * (-(-d // LANES) * LANES) * 4
+
+
+def fused_backward(q_shape, k_shape) -> bool:
+    """Whether a call with q ``(B, H, Sq, D)`` and k ``(B, KV, Sk, D)``
+    runs the fused backward (else the split pair)."""
+    return plans(q_shape, k_shape, causal=True, interpret=False)[1].fused
+
+
 @dataclasses.dataclass(frozen=True)
 class _Plan:
     """Static shape of one kernel call: blocks, masking, grid extents."""
@@ -96,6 +133,7 @@ class _Plan:
     sk_valid: int  # keys at or past it are padding
     q_offset: int  # absolute position of q row 0 relative to key 0
     interpret: bool
+    fused: bool  # backward: one kernel with dQ resident in VMEM
 
     def last_k(self, qi):
         """The last kv block that q block ``qi`` needs."""
@@ -351,13 +389,12 @@ def backward_dq(q, k, v, do, lse, di, plan: _Plan):
     )(q, k, v, do, lse, di)
 
 
-def backward_dkv(q, k, v, do, lse, di, plan: _Plan):
-    """(dK, dV): (B, KV, Sk, D) each; the arguments as ``backward_dq``'s.
-
-    Each kv block accumulates over the q blocks and over the ``n_sub``
-    blocks of ``group`` query heads that share its kv head, both inner
-    sequential grid axes."""
-    B, KV, Sk, D = k.shape
+def _kv_major(k, plan: _Plan):
+    """(grid, in_specs, kv-block spec) of a backward kernel whose grid walks
+    kv blocks, with inner sequential axes over the ``n_sub`` blocks of
+    ``group`` query heads that share the kv head and over the q blocks; the
+    inputs are ``backward_dq``'s arguments."""
+    B, KV, _, D = k.shape
     bq, bk, G, n_sub = plan.bq, plan.bk, plan.group, plan.n_sub
 
     def q_map(b, g, j, sub, i):
@@ -366,27 +403,30 @@ def backward_dkv(q, k, v, do, lse, di, plan: _Plan):
     def row_map(b, g, j, sub, i):
         return b, g * n_sub + sub, 0, jnp.maximum(i, plan.first_q(j))
 
-    def kv_map(b, g, j, sub, i):
-        return b, g, j, 0
+    kv = pl.BlockSpec((1, 1, bk, D), lambda b, g, j, sub, i: (b, g, j, 0))
+    q_block = pl.BlockSpec((1, G, bq, D), q_map)
+    row = pl.BlockSpec((1, G, 1, bq), row_map)
+    return ((B, KV, plan.nk, n_sub, plan.nq),
+            [q_block, kv, kv, q_block, row, row], kv)
 
+
+def backward_dkv(q, k, v, do, lse, di, plan: _Plan):
+    """(dK, dV): (B, KV, Sk, D) each; the arguments as ``backward_dq``'s.
+
+    Each kv block accumulates over the q blocks and over the ``n_sub``
+    blocks of ``group`` query heads that share its kv head, both inner
+    sequential grid axes."""
+    grid, in_specs, kv = _kv_major(k, plan)
     return pl.pallas_call(
         functools.partial(_dkv_kernel, plan=plan),
-        grid=(B, KV, plan.nk, n_sub, plan.nq),
-        in_specs=[
-            pl.BlockSpec((1, G, bq, D), q_map),
-            pl.BlockSpec((1, 1, bk, D), kv_map),
-            pl.BlockSpec((1, 1, bk, D), kv_map),
-            pl.BlockSpec((1, G, bq, D), q_map),
-            pl.BlockSpec((1, G, 1, bq), row_map),
-            pl.BlockSpec((1, G, 1, bq), row_map),
-        ],
-        out_specs=[pl.BlockSpec((1, 1, bk, D), kv_map),
-                   pl.BlockSpec((1, 1, bk, D), kv_map)],
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=[kv, kv],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[
-            pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
+            pltpu.VMEM((plan.bk, k.shape[3]), jnp.float32),
+            pltpu.VMEM((plan.bk, k.shape[3]), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -396,9 +436,91 @@ def backward_dkv(q, k, v, do, lse, di, plan: _Plan):
     )(q, k, v, do, lse, di)
 
 
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dk_ref,
+                dv_ref, dq_acc, dk_acc, dv_acc, *, plan: _Plan):
+    ki, sub, qi = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    first = jnp.logical_and(sub == 0, qi == 0)
+    last = jnp.logical_and(sub == plan.n_sub - 1, qi == plan.nq - 1)
+
+    @pl.when(jnp.logical_and(ki == 0, first))
+    def _init_dq():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(first)
+    def _init_dkv():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def body(masked):
+        k, v = k_ref[0, 0], v_ref[0, 0]
+        rows = pl.ds(pl.multiple_of(qi * plan.bq, plan.bq), plan.bq)
+        for r in range(plan.group):  # query heads of this kv head
+            q, do = q_ref[0, r], do_ref[0, r]
+            st = jax.lax.dot_general(k, q, _NT,
+                                     preferred_element_type=jnp.float32
+                                     ) * plan.scale  # (bk, bq)
+            if masked:
+                st = jnp.where(plan.valid(qi, ki, st.shape, 1), st, NEG_INF)
+            pt = jnp.exp(st - lse_ref[0, r])
+            dv_acc[...] += jnp.dot(pt.astype(do.dtype), do,
+                                   preferred_element_type=jnp.float32)
+            dpt = jax.lax.dot_general(v, do, _NT,
+                                      preferred_element_type=jnp.float32)
+            dst = (pt * (dpt - di_ref[0, r])).astype(q.dtype)
+            dk_acc[...] += jnp.dot(dst, q, preferred_element_type=jnp.float32)
+            dq_acc[sub * plan.group + r, rows, :] += jax.lax.dot_general(
+                dst, k, _TN, preferred_element_type=jnp.float32)  # (bq, D)
+
+    plan.run(qi, ki, body)
+
+    @pl.when(last)
+    def _done_kv():
+        dk_ref[0, 0] = (dk_acc[...] * plan.scale).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(jnp.logical_and(ki == plan.nk - 1, last))
+    def _done_q():
+        for h in range(dq_acc.shape[0]):  # a head at a time: no full temp
+            dq_ref[0, h] = (dq_acc[h] * plan.scale).astype(dq_ref.dtype)
+
+
+def backward_fused(q, k, v, do, lse, di, plan: _Plan):
+    """(dQ, dK, dV) in one kernel; the arguments as ``backward_dq``'s.
+
+    The grid is ``backward_dkv``'s; dQ of the kv head's query heads stays
+    resident in VMEM across its KV blocks, so that axis is sequential."""
+    _, H, Sq, D = q.shape
+    n_rep = H // k.shape[1]
+    grid, in_specs, kv = _kv_major(k, plan)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, plan=plan),
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=[pl.BlockSpec((1, n_rep, Sq, D),
+                                lambda b, g, j, sub, i: (b, g, 0, 0)),
+                   kv, kv],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[
+            pltpu.VMEM((n_rep, Sq, D), jnp.float32),
+            pltpu.VMEM((plan.bk, D), jnp.float32),
+            pltpu.VMEM((plan.bk, D), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_SCOPED + dq_resident_bytes(n_rep, Sq, D)),
+        interpret=plan.interpret,
+        name="flash_attention_bwd",
+    )(q, k, v, do, lse, di)
+
+
 def _backward(q, k, v, o, lse, do, plan: _Plan):
     di = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                  axis=-1)[:, :, None, :]  # (B, H, 1, Sq)
+    if plan.fused:
+        return backward_fused(q, k, v, do, lse, di, plan)
     dk, dv = backward_dkv(q, k, v, do, lse, di, plan)
     return backward_dq(q, k, v, do, lse, di, plan), dk, dv
 
@@ -425,7 +547,8 @@ def plans(q_shape, k_shape, *, causal: bool, interpret: bool,
     """(forward plan, backward plan) of a call with q ``(B, H, Sq, D)`` and
     k ``(B, KV, Sk, D)``, over sequences padded to whole blocks of both.
     ``bq``/``bk`` set every kernel's blocks; by default ``blocks`` chooses
-    them from the shapes."""
+    them from the shapes. The backward is fused where its resident dQ fits
+    ``VMEM_DQ_BYTES``."""
     _, H, Sq, D = q_shape
     KV, Sk = k_shape[1], k_shape[2]
     if H % KV:
@@ -438,13 +561,15 @@ def plans(q_shape, k_shape, *, causal: bool, interpret: bool,
     sq = Sq + (-Sq) % max(fwd[0], bwd[0])
     sk = Sk + (-Sk) % max(fwd[1], bwd[1])
 
-    def plan(bq, bk):
+    def plan(bq, bk, fused=False):
         group = heads_per_step(H // KV, bq, D)
         return _Plan(scale=D ** -0.5, bq=bq, bk=bk, nq=sq // bq, nk=sk // bk,
                      group=group, n_sub=H // KV // group, causal=causal,
-                     sk_valid=Sk, q_offset=Sk - Sq, interpret=interpret)
+                     sk_valid=Sk, q_offset=Sk - Sq, interpret=interpret,
+                     fused=fused)
 
-    return plan(*fwd), plan(*bwd)
+    fused = dq_resident_bytes(H // KV, sq, D) <= VMEM_DQ_BYTES
+    return plan(*fwd), plan(*bwd, fused)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk", "interpret"))

@@ -241,8 +241,10 @@ _PATHS: contextvars.ContextVar = contextvars.ContextVar("attention_paths",
 def attention_paths():
     """A tally, by path (``flash``, ``chunked``, ``decode``), of the
     attention calls traced while it is open: how often the flash kernel
-    engages in a configuration. A layer ``scan`` traces its body once, and
-    a jit cache hit traces nothing."""
+    engages in a configuration. Each flash call also counts the backward
+    its shapes take, ``flash_bwd_fused`` or ``flash_bwd_split``
+    (``flash_attention.fused_backward``). A layer ``scan`` traces its body
+    once, and a jit cache hit traces nothing."""
     tally = collections.Counter()
     token = _PATHS.set(tally)
     try:
@@ -347,6 +349,8 @@ def attention_block(
             out = decode_attention(q, k_cache, v_cache, valid_len)
         elif heads_first:
             _count("flash")
+            _count("flash_bwd_fused" if fa.fused_backward(q.shape, kx.shape)
+                   else "flash_bwd_split")
             out = flash(q, kx, vx)
             if collect_kv:  # the cache is (B, S, KV, D)
                 kx, vx = jnp.swapaxes(kx, 1, 2), jnp.swapaxes(vx, 1, 2)

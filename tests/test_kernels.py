@@ -202,6 +202,84 @@ def test_flash_attention_grads_over_several_head_blocks_per_kv_head(
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4, err_msg=name)
 
 
+@pytest.fixture
+def fa_traced_anew():
+    """The kernel module, with ``flash_attention`` traced anew before and
+    after the test: its backward is chosen at trace time from module
+    constants that the test patches."""
+    from repro.kernels import flash_attention as fa
+
+    fa.flash_attention.clear_cache()
+    yield fa
+    fa.flash_attention.clear_cache()
+
+
+@pytest.mark.parametrize("H,KV,Sq,Sk,causal,head_rows", [
+    (9, 3, 256, 256, True, None),        # GQA 9/3, smollm's grouping
+    (4, 1, 256, 256, True, None),        # MQA
+    (8, 2, 256, 256, True, 2 * 128 * 32),  # two head blocks per kv head
+    (4, 2, 200, 200, True, None),        # keys padded to whole blocks
+    (4, 2, 256, 256, False, None),
+    (4, 2, 128, 256, False, None),       # more keys than queries
+])
+def test_fused_backward_matches_split_backward_and_reference(
+        monkeypatch, fa_traced_anew, H, KV, Sq, Sk, causal, head_rows):
+    """dq/dk/dv of the fused backward kernel, and of the split pair forced
+    by a VMEM budget of nothing, against ``jax.grad`` of the oracle in f32;
+    each runs the kernels its plan names."""
+    import re
+
+    fa = fa_traced_anew
+    D = 32
+    if head_rows:
+        monkeypatch.setattr(fa, "VMEM_HEAD_ROWS", head_rows)
+    ks = jax.random.split(jax.random.PRNGKey(H * Sq + Sk), 4)
+    q = jax.random.normal(ks[0], (1, H, Sq, D), jnp.float32)
+    k = jax.random.normal(ks[1], (1, KV, Sk, D), jnp.float32)
+    v = jax.random.normal(ks[2], (1, KV, Sk, D), jnp.float32)
+    do = jax.random.normal(ks[3], (1, H, Sq, D), jnp.float32)
+
+    def grads(f):
+        return jax.grad(lambda *a: jnp.sum(f(*a) * do), (0, 1, 2))(q, k, v)
+
+    def flash(q, k, v):
+        return ops.flash_attention(q, k, v, causal=causal, bq=128, bk=128)
+
+    def kernels():
+        fa.flash_attention.clear_cache()
+        text = str(jax.make_jaxpr(lambda: grads(flash))())
+        return sorted(set(re.findall(r"flash_attention_\w+", text)))
+
+    def backward_plan():
+        return fa.plans(q.shape, k.shape, causal=causal, interpret=True,
+                        bq=128, bk=128)[1]
+
+    assert backward_plan().fused
+    assert (backward_plan().n_sub > 1) == bool(head_rows)
+    assert kernels() == ["flash_attention_bwd", "flash_attention_fwd"]
+    fused = grads(flash)
+    monkeypatch.setattr(fa, "VMEM_DQ_BYTES", 0)
+    assert not backward_plan().fused
+    assert kernels() == ["flash_attention_dkv", "flash_attention_dq",
+                         "flash_attention_fwd"]
+    split = grads(flash)
+    want = grads(lambda *a: ref.attention_ref(*a, causal=causal))
+    for name, a, b, c in zip(("dq", "dk", "dv"), fused, split, want):
+        np.testing.assert_allclose(a, c, rtol=1e-4, atol=1e-4, err_msg=name)
+        np.testing.assert_allclose(b, c, rtol=1e-4, atol=1e-4, err_msg=name)
+
+
+def test_backward_is_fused_where_its_dq_fits_vmem():
+    """The shape predicate: smollm's attention (GQA 9/3, S 2048, head dim
+    64: 9 MiB of resident dQ) takes the fused backward; 8 query heads a kv
+    head over 32768 positions of head dim 128 (384 MiB) the split pair."""
+    from repro.kernels import flash_attention as fa
+
+    assert fa.dq_resident_bytes(3, 2048, 64) == 9 << 20
+    assert fa.fused_backward((8, 9, 2048, 64), (8, 3, 2048, 64))
+    assert not fa.fused_backward((1, 8, 32768, 128), (1, 1, 32768, 128))
+
+
 # ---------------------------------------------------------------------------
 # which attention path the model takes
 # ---------------------------------------------------------------------------
@@ -246,14 +324,18 @@ def test_attention_takes_the_flash_kernel_only_where_it_is_exact(
         monkeypatch, name, path):
     """With the program traced for a TPU, only causal self-attention with
     no window or softcap, over whole blocks of an unsharded sequence, takes
-    the kernel; the per-trace tally counts the path each call took."""
+    the kernel; the per-trace tally counts the path each call took, and
+    for a flash call the backward its shapes take."""
     from repro.models import common as cm
 
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
     fn, args = _routing_case(name)
     with cm.attention_paths() as tally:
         jax.eval_shape(fn, *args)
-    assert dict(tally) == {path: 1}
+    want = {path: 1}
+    if path == "flash":
+        want["flash_bwd_fused"] = 1
+    assert dict(tally) == want
 
 
 def test_softcapped_attention_is_not_the_flash_kernels(monkeypatch):
@@ -282,7 +364,7 @@ def test_train_step_through_the_flash_kernel_matches_chunked_attention(
         monkeypatch):
     """The smoke smollm's loss and gradients through the kernel (interpret
     mode) equal those through ``chunked_attention``; the traced step's
-    tally holds only flash calls."""
+    tally holds only flash calls, with the fused backward."""
     from repro import configs
     from repro.config import ShapeConfig
     from repro.models import common as cm
@@ -299,7 +381,7 @@ def test_train_step_through_the_flash_kernel_matches_chunked_attention(
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
     with cm.attention_paths() as tally:  # a new function: traced anew
         loss1, g1 = jax.jit(jax.value_and_grad(b.loss))(params, batch)
-    assert set(tally) == {"flash"}
+    assert set(tally) == {"flash", "flash_bwd_fused"}
     np.testing.assert_allclose(float(loss1), float(loss0), rtol=1e-3)
     for a, c in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
         a, c = np.asarray(a, np.float32), np.asarray(c, np.float32)

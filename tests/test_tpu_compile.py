@@ -21,8 +21,8 @@ from repro.models import common as cm
 
 CFG = configs.get("smollm-135m")  # d_model 576, d_ff 1536, GQA 9/3, hd 64
 SEQ, BATCH = 2048, 8
-PARENT_STEP_TEMP = 6_347_916_288  # the all-HBM step's scratch before the
-# flash kernel took its attention (chunked_attention's stacked residuals)
+SPLIT_STEP_TEMP = 5_969_461_248  # the all-HBM step's scratch with the flash
+# kernel's split backward: the fused one holds dQ in VMEM, not HBM
 
 
 @pytest.fixture(scope="module")
@@ -92,12 +92,16 @@ def _kernel_cases(chip):
         "flash_attention_dkv": (
             lambda *a: flash_attention.backward_dkv(*a, bwd),
             [q, kv, kv, q, rows, rows]),
+        "flash_attention_bwd": (
+            lambda *a: flash_attention.backward_fused(*a, bwd),
+            [q, kv, kv, q, rows, rows]),
     }
 
 
 @pytest.mark.parametrize("name", ["fused_adam", "tiled_matmul",
                                   "quantized_matmul", "flash_attention",
-                                  "flash_attention_dq", "flash_attention_dkv"])
+                                  "flash_attention_dq", "flash_attention_dkv",
+                                  "flash_attention_bwd"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, args = _kernel_cases(one_chip)[name]
     compiled = jax.jit(fn).lower(*args).compile()
@@ -130,9 +134,10 @@ def test_host_tier_train_step_compiles_for_v5e(host_step):
 
 def test_every_op_of_the_v5e_step_is_in_a_model_region(host_step):
     """On the TPU every fusion, dot and convolution of the step carries the
-    name of a model region (``jax.named_scope``), and the copies between
-    pinned host memory and HBM, which carry no name, are the ``offload``
-    region by their memory space."""
+    name of a model region (``jax.named_scope``), every region has ops of
+    its own (``attn/core``'s are the flash kernels' calls), and the copies
+    between pinned host memory and HBM, which carry no name, are the
+    ``offload`` region by their memory space."""
     from perfbench import regions
 
     text = host_step[1].as_text()
@@ -140,7 +145,8 @@ def test_every_op_of_the_v5e_step_is_in_a_model_region(host_step):
     tops = regions.top_level_ops(text)
     assert len(tops) > 100
     assert [t for t in tops if rmap[t][0] == regions.OTHER] == []
-    assert {r for r, _ in map(rmap.get, tops)} >= set(regions.REGIONS) - {
+    assert {r for r, _ in map(rmap.get, tops + _kernels(text))} >= set(
+        regions.REGIONS) - {
         "moe/router", "moe/dispatch", "moe/experts", "moe/combine",
         "offload"}
     host_copies = [t for t in regions.top_level_ops(text, ("copy-start",))
@@ -179,14 +185,15 @@ def _kernels(text):
 def test_all_hbm_step_runs_attention_through_the_flash_kernel_on_v5e(
         hbm_step):
     """Compiled for the chip, the all-HBM step takes the flash kernel: its
-    forward (once more in the rematerialised forward) and both backward
-    kernels are the step's only Pallas calls, all in the ``attn/core``
+    forward (once more in the rematerialised forward) and its fused
+    backward are the step's only Pallas calls, all in the ``attn/core``
     region; every fusion, dot and convolution keeps a model region; and the
-    step's scratch is no more than it was with ``chunked_attention``."""
+    step's scratch is no more than it was with the split backward."""
     from perfbench import regions
 
     compiled, tally = hbm_step
-    assert tally == {"flash": 1}  # the layer scan traces its body once
+    # the layer scan traces its body once
+    assert tally == {"flash": 1, "flash_bwd_fused": 1}
     text = compiled.as_text()
     rmap = regions.region_map(text)
     tops = regions.top_level_ops(text)
@@ -195,9 +202,26 @@ def test_all_hbm_step_runs_attention_through_the_flash_kernel_on_v5e(
     assert {rmap[t] for t in kernels} == {
         ("attn/core", "fwd"), ("attn/core", "remat"), ("attn/core", "bwd")}
     assert sorted(t.rsplit(".", 1)[0] for t in kernels) == [
-        "flash_attention_dkv", "flash_attention_dq", "flash_attention_fwd",
-        "flash_attention_fwd"]
-    assert compiled.memory_analysis().temp_size_in_bytes <= PARENT_STEP_TEMP
+        "flash_attention_bwd", "flash_attention_fwd", "flash_attention_fwd"]
+    assert compiled.memory_analysis().temp_size_in_bytes <= SPLIT_STEP_TEMP
+
+
+def test_split_backward_compiles_where_dq_outgrows_vmem(one_chip):
+    """8 query heads a kv head over 32768 positions of head dim 128 hold
+    more dQ than VMEM: the gradient compiles with the split pair."""
+    q = _spec((1, 8, 32768, 128), jnp.bfloat16, one_chip)
+    kv = _spec((1, 1, 32768, 128), jnp.bfloat16, one_chip)
+    assert not flash_attention.fused_backward(q.shape, kv.shape)
+
+    def loss(q, k, v):
+        o = flash_attention.flash_attention(q, k, v, causal=True,
+                                            interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile(
+    ).as_text()
+    assert sorted(t.rsplit(".", 1)[0] for t in _kernels(text)) == [
+        "flash_attention_dkv", "flash_attention_dq", "flash_attention_fwd"]
 
 
 def test_flash_attention_runs_per_device_over_four_chips(topo):
@@ -220,6 +244,6 @@ def test_flash_attention_runs_per_device_over_four_chips(topo):
     with jax.set_mesh(mesh), cm.attention_paths() as tally:
         lowered = jax.jit(jax.grad(loss, (0, 1))).lower(p, x, pos)
     text = lowered.compile().as_text()
-    assert tally == {"flash": 1}
-    assert len(_kernels(text)) == 3  # forward, dq, dk/dv
+    assert tally == {"flash": 1, "flash_bwd_fused": 1}
+    assert len(_kernels(text)) == 2  # forward, fused backward
     assert "all-gather" not in text and "all-to-all" not in text
